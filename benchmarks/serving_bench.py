@@ -433,6 +433,8 @@ def main():
     ap.add_argument("--obs-out", default="BENCH_serving_obs.json",
                     help="utilization digest path for the obs run")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.run_async:
         report = async_compare(args.requests, args.gen, args.rate,
                                speed=args.speed)

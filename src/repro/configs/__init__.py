@@ -7,6 +7,8 @@ from repro.configs.chameleon_34b import CONFIG as CHAMELEON_34B
 from repro.configs.gemma3_12b import CONFIG as GEMMA3_12B
 from repro.configs.llama3_405b import CONFIG as LLAMA3_405B
 from repro.configs.llama4_maverick_400b import CONFIG as LLAMA4_MAVERICK
+from repro.configs.mixtral_8x7b_v5e_pair import DRAFT as MISTRAL_7B_4L
+from repro.configs.mixtral_8x7b_v5e_pair import TARGET as MIXTRAL_8X7B_2L
 from repro.configs.phi3_medium_14b import CONFIG as PHI3_MEDIUM
 from repro.configs.phi35_moe_42b import CONFIG as PHI35_MOE
 from repro.configs.recurrentgemma_2b import CONFIG as RECURRENTGEMMA_2B
@@ -33,6 +35,14 @@ PAPER_MODELS = {
     "mixtral-8x7b": MIXTRAL_8X7B,
     "mixtral-8x22b": MIXTRAL_8X22B,
     "mistral-7b": MISTRAL_7B,
+    "mixtral-8x7b-2l": MIXTRAL_8X7B_2L,
+    "mistral-7b-4l": MISTRAL_7B_4L,
+}
+
+# Target/draft pairs served as they are, at published widths (no
+# ``.reduced()``); see repro/configs/mixtral_8x7b_v5e_pair.py.
+PAIRS = {
+    "mixtral-8x7b-v5e-pair": (MIXTRAL_8X7B_2L, MISTRAL_7B_4L),
 }
 
 ALL_CONFIGS = {**ARCHS, **PAPER_MODELS}
@@ -45,5 +55,5 @@ def get_config(name: str) -> ModelConfig:
         raise KeyError(f"unknown arch {name!r}; known: {sorted(ALL_CONFIGS)}")
 
 
-__all__ = ["ARCHS", "PAPER_MODELS", "ALL_CONFIGS", "get_config",
+__all__ = ["ARCHS", "PAPER_MODELS", "PAIRS", "ALL_CONFIGS", "get_config",
            "ModelConfig", "InputShape", "INPUT_SHAPES", "base"]

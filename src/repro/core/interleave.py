@@ -4,8 +4,9 @@ The paper runs two batches in anti-phase: in slot t_n the *target* verifies
 batch 1 while the *draft* generates candidates for batch 0; the roles swap
 in t_{n+1}.  On GPU this needs two processes + shared memory (paper App.
 A.2); in JAX the same concurrency is expressed as ONE fused jit step that
-contains both computations — XLA schedules the draft model's matmuls into
-the slack left by the target's streamed-weight copies (DESIGN.md §2).
+contains both computations, which XLA may schedule side by side.  The
+serving path keeps the target device-resident, so there are no
+streamed-weight copies in the step yet for the draft to hide.
 
 Stepwise API (continuous-batching ready)
 ----------------------------------------
@@ -220,6 +221,23 @@ class InterleavedPipeline:
             elif n == 0:
                 ctr.inc(0, entry=entry)   # materialize the zero series
 
+    def _fused_args(self, verify: BatchState, gen: BatchState) -> tuple:
+        assert verify.drafts is not None, "verify batch has no staged drafts"
+        vstate = {"target_cache": verify.target_cache,
+                  "t_next": verify.t_next, "drafts": verify.drafts}
+        if self.tree is not None:
+            vstate["draft_cache"] = verify.draft_cache
+        dstate = {"draft_cache": gen.draft_cache, "t_next": gen.t_next}
+        return (self.tp, self.tcfg, self.dp, self.dcfg, vstate, dstate,
+                self.tree if self.tree is not None else self.n_cand,
+                self.mesh)
+
+    def lower_fused(self, verify: BatchState, gen: BatchState):
+        """The fused step lowered for these two states without running
+        it (``.compile().as_text()`` shows the program each round
+        executes).  ``verify`` must hold staged drafts."""
+        return self._fused.lower(*self._fused_args(verify, gen))
+
     # ------------------------------------------------------------------
     def warmup(self, state: BatchState) -> None:
         """Slot t_0 (Fig. 4): draft candidates for ``state`` so the next
@@ -251,24 +269,15 @@ class InterleavedPipeline:
         when the caller does its own per-slot bookkeeping, so a
         long-running server doesn't grow the emitted log unboundedly.
         """
-        assert verify.drafts is not None, "verify batch has no staged drafts"
         assert gen.drafts is None, "gen batch already holds drafts"
         t_round0 = time.perf_counter()
-        vstate = {"target_cache": verify.target_cache,
-                  "t_next": verify.t_next, "drafts": verify.drafts}
-        if self.tree is not None:
-            vstate["draft_cache"] = verify.draft_cache
-        dstate = {"draft_cache": gen.draft_cache, "t_next": gen.t_next}
         tr = self.obs.tracer
         # The fused call is ONE XLA program doing both phases; record it
         # as anti-phase twins — a verify span plus a mirrored draft span
         # over the same interval (bubble accounting unions the overlap,
         # so device-busy time is not double counted).
         with tr.span("target_verify", "verify(fused)", cat="device") as sp:
-            vout, dout = self._fused(self.tp, self.tcfg, self.dp, self.dcfg,
-                                     vstate, dstate,
-                                     self.tree if self.tree is not None
-                                     else self.n_cand, self.mesh)
+            vout, dout = self._fused(*self._fused_args(verify, gen))
             sp.fence((vout, dout))
         if tr.enabled:
             tr.complete("draft_generate", "draft(fused)", sp.t0, sp.t1,

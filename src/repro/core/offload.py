@@ -1,20 +1,18 @@
 """Host<->HBM weight streaming: the TPU-native realization of the paper's
-PCIe offloading (DESIGN.md §2).
+PCIe offloading.
 
 * Target weights at rest live in ``pinned_host`` memory (the analogue of
-  the paper's CPU DRAM tier); per layer-group slabs are copied into device
-  memory *inside the jit'd step* via ``jax.device_put`` — XLA issues these
-  as asynchronous copies that overlap with compute, which is exactly the
-  paper's prefetch pipeline without any host threading.
+  the paper's CPU DRAM tier) and are copied into device memory per step
+  with ``jax.device_put``.
 * The KV cache may also live host-side, with decode attention computed
   under ``jax.experimental.compute_on('device_host')`` — the analogue of
   the paper's CPU-attention leg (§4.1.2).
 * The draft model stays fully device-resident (the paper's "low-yield
   memory repurposing").
 
-On this CPU-only container the memory spaces are both host RAM, but the
-placement logic, copy schedule, and compiled HLO (with explicit
-``memory_kind`` annotations) are the real thing.
+:class:`OffloadedModel` is not on the serving path yet: ``ServingEngine``
+keeps the target resident, and :meth:`OffloadedModel.stream_layers`
+copies the whole layer stack before each jitted call.
 """
 from __future__ import annotations
 
@@ -23,6 +21,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.compute_on import compute_on
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
@@ -30,40 +29,19 @@ from repro.models.transformer import (forward_decoder, init_cache,
                                       logits_from_hidden)
 from repro.obs import NULL_OBS
 
-try:
-    from jax.experimental.compute_on import compute_on
-    HAS_COMPUTE_ON = True
-except ImportError:  # pragma: no cover
-    HAS_COMPUTE_ON = False
-
-
-def _memory_kinds(device) -> set:
-    try:
-        return {m.kind for m in device.addressable_memories()}
-    except Exception:  # pragma: no cover - very old jax
-        return set()
-
 
 def host_memory_kind(device=None) -> str:
-    """The memory kind the host offload tier actually maps to on this
-    backend: 'pinned_host' where exposed, else the device default (e.g.
-    CPU on older jax only has 'unpinned_host')."""
+    """The memory kind of the host offload tier: ``pinned_host``."""
     device = device or jax.devices()[0]
-    if "pinned_host" in _memory_kinds(device):
-        return "pinned_host"
-    try:
-        return device.default_memory().kind
-    except Exception:  # pragma: no cover - very old jax
-        return "device"
+    kinds = {m.kind for m in device.addressable_memories()}
+    if "pinned_host" not in kinds:
+        raise ValueError(f"{device} exposes no pinned_host memory "
+                         f"(has {sorted(kinds)})")
+    return "pinned_host"
 
 
 def _sharding(memory_kind: str, device=None):
     device = device or jax.devices()[0]
-    if memory_kind not in _memory_kinds(device):
-        # this backend/jax doesn't expose the tier (e.g. CPU on older jax
-        # has only 'unpinned_host'): fall back to the default space — the
-        # copy schedule stays identical, only the annotation is dropped
-        return jax.sharding.SingleDeviceSharding(device)
     return jax.sharding.SingleDeviceSharding(device, memory_kind=memory_kind)
 
 
@@ -116,7 +94,7 @@ class OffloadedModel:
     def __init__(self, cfg: ModelConfig, params: dict,
                  host_kv: bool = False, obs=None):
         self.cfg = cfg
-        self.host_kv = host_kv and HAS_COMPUTE_ON
+        self.host_kv = host_kv
         self.obs = obs if obs is not None else NULL_OBS
         resident = {k: v for k, v in params.items() if k != "layers"}
         self.params_resident = put_device(resident)
@@ -184,8 +162,6 @@ def host_attention_direct(q, k, v, mask, scale):
     paper's CPU attention.
     """
     from repro.models.attention import attention_direct
-    if not HAS_COMPUTE_ON:
-        return attention_direct(q, k, v, mask, scale)
     with compute_on("device_host"):
         out = attention_direct(q, k, v, mask, scale)
     return out

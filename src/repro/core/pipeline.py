@@ -96,8 +96,15 @@ class SpecOffloadEngine:
         self._pipe = None
 
     def init_from_seed(self, seed: int = 0):
+        """Random weights from ``seed``, built under ``jax.jit`` one model
+        at a time, so the float32 sampling temporaries of one model are
+        freed before the next is built (eager init keeps several alive
+        beside the finished leaves and overflows 16 GB at published
+        widths)."""
         k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
-        self.load(M.init_params(self.tcfg, k1), M.init_params(self.dcfg, k2))
+        init = jax.jit(M.init_params, static_argnums=0)
+        tp = jax.block_until_ready(init(self.tcfg, k1))
+        self.load(tp, init(self.dcfg, k2))
 
     def plan(self, prompt_len: int, gen_len: int,
              accept_prob: float = 0.7, occupancy: float = 1.0) -> Policy:

@@ -1,68 +1,69 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""jit'd public wrappers for the Pallas kernels, and the one switch that
+routes the model between compiled kernels and their references.
 
-On a real TPU these dispatch the compiled kernels; on the CPU container
-``interpret=True`` executes the kernel bodies in Python for correctness
-validation (the repo-wide convention; see DESIGN.md §7).  ``INTERPRET``
-defaults to True when no TPU is present.
+:func:`use_compiled_kernels` is asked at trace time, never at import, so
+importing this module starts no JAX backend.  On a TPU it is True: the
+wrappers dispatch the compiled kernels and the model's paged decode goes
+through :func:`paged_decode_attention`.  Elsewhere it is False: wrappers
+default to ``interpret=True`` (the kernel bodies run in Python, for
+correctness checks) and the model takes the ``kernels/ref.py`` gather
+path.  Tests steer both call sites at once with :func:`compiled_kernels`.
 """
 from __future__ import annotations
 
-from functools import partial
+import contextlib
+import contextvars
+import functools
 
 import jax
 
 from repro.kernels import (decode_attention as _da, flash_attention as _fa,
                            moe_ffn as _mf, rglru_scan as _rg, wkv6 as _wk)
 
-INTERPRET = jax.default_backend() != "tpu"
+_FORCED = contextvars.ContextVar("compiled_kernels", default=None)
 
 
-@partial(jax.jit, static_argnames=("scale", "causal", "window", "block_q",
-                                   "block_k", "interpret"))
-def flash_attention(q, k, v, *, scale=None, causal=True, window=None,
-                    block_q=128, block_k=128, interpret=None):
-    interpret = INTERPRET if interpret is None else interpret
-    return _fa.flash_attention(q, k, v, scale=scale, causal=causal,
-                               window=window, block_q=block_q,
-                               block_k=block_k, interpret=interpret)
+def use_compiled_kernels() -> bool:
+    """True: compiled Pallas kernels; False: references / interpret mode.
+
+    Defaults to whether the default backend is a TPU; a
+    :func:`compiled_kernels` scope overrides it."""
+    forced = _FORCED.get()
+    if forced is not None:
+        return forced
+    return jax.default_backend() == "tpu"
 
 
-@partial(jax.jit, static_argnames=("scale", "window", "block_k", "interpret"))
-def decode_attention(q, k, v, lengths, *, scale=None, window=None,
-                     block_k=256, anc_bits=None, interpret=None):
-    interpret = INTERPRET if interpret is None else interpret
-    return _da.decode_attention(q, k, v, lengths, scale=scale, window=window,
-                                block_k=block_k, anc_bits=anc_bits,
-                                interpret=interpret)
+@contextlib.contextmanager
+def compiled_kernels(on: bool):
+    """Force :func:`use_compiled_kernels` to ``on`` inside the scope (e.g.
+    to compile the TPU kernels for a described, unattached chip)."""
+    tok = _FORCED.set(on)
+    try:
+        yield
+    finally:
+        _FORCED.reset(tok)
 
 
-@partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                           k_scale=None, v_scale=None, scale=None,
-                           anc_bits=None, interpret=None):
-    interpret = INTERPRET if interpret is None else interpret
-    return _da.paged_decode_attention(
-        q, k_pool, v_pool, block_tables, lengths, k_scale=k_scale,
-        v_scale=v_scale, scale=scale, anc_bits=anc_bits,
-        interpret=interpret)
+def _wrap(fn, *static):
+    """jit ``fn`` with ``static`` + ``interpret`` static, resolving
+    ``interpret=None`` through :func:`use_compiled_kernels` *before* the
+    jit cache lookup so a forced scope never reuses the other mode's
+    trace."""
+    jitted = jax.jit(fn, static_argnames=static + ("interpret",))
+
+    @functools.wraps(fn)
+    def call(*args, interpret=None, **kwargs):
+        if interpret is None:
+            interpret = not use_compiled_kernels()
+        return jitted(*args, interpret=interpret, **kwargs)
+    return call
 
 
-@partial(jax.jit, static_argnames=("activation", "block_c", "block_f",
-                                   "interpret"))
-def moe_ffn(buf, w_gate, w_up, w_down, *, activation="swiglu", block_c=128,
-            block_f=512, interpret=None):
-    interpret = INTERPRET if interpret is None else interpret
-    return _mf.moe_ffn(buf, w_gate, w_up, w_down, activation=activation,
-                       block_c=block_c, block_f=block_f, interpret=interpret)
-
-
-@partial(jax.jit, static_argnames=("block_w", "interpret"))
-def rglru_scan(a, gated, h0, *, block_w=256, interpret=None):
-    interpret = INTERPRET if interpret is None else interpret
-    return _rg.rglru_scan(a, gated, h0, block_w=block_w, interpret=interpret)
-
-
-@partial(jax.jit, static_argnames=("interpret",))
-def wkv6(r, k, v, w, u, s0, *, interpret=None):
-    interpret = INTERPRET if interpret is None else interpret
-    return _wk.wkv6(r, k, v, w, u, s0, interpret=interpret)
+flash_attention = _wrap(_fa.flash_attention, "scale", "causal", "window",
+                        "block_q", "block_k")
+decode_attention = _wrap(_da.decode_attention, "scale", "window", "block_k")
+paged_decode_attention = _wrap(_da.paged_decode_attention, "scale")
+moe_ffn = _wrap(_mf.moe_ffn, "activation", "block_c", "block_f")
+rglru_scan = _wrap(_rg.rglru_scan, "block_w")
+wkv6 = _wrap(_wk.wkv6)

@@ -91,7 +91,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str,
 
     from repro.configs import get_config
     from repro.configs.base import INPUT_SHAPES
-    from repro.launch.mesh import activate_mesh, make_production_mesh
+    from repro.launch.mesh import make_production_mesh
     from repro.launch.specs import applicable, build_step
 
     cfg = get_config(arch)
@@ -108,7 +108,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str,
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     n_dev = 512 if mesh_kind == "multi" else 256
     t0 = time.time()
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, args, donate = build_step(cfg, shape, mesh)
         lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0

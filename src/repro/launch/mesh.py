@@ -2,7 +2,8 @@
 
 ``make_production_mesh`` is a FUNCTION (not a module constant) so importing
 this module never touches jax device state — required because the dry-run
-must set XLA_FLAGS before the first jax call.
+must set XLA_FLAGS before the first jax call.  Install a mesh as the
+ambient one with ``jax.set_mesh(mesh)``.
 
 Mesh shapes (TPU v5e):
   single-pod: (data=16, model=16)              — 256 chips
@@ -10,36 +11,28 @@ Mesh shapes (TPU v5e):
 """
 from __future__ import annotations
 
+import math
+
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` only exists on
-    newer releases (older ones are Auto-only anyway)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def activate_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh:
-    ``jax.set_mesh`` on newer jax, the legacy ``with mesh:`` scope (which
-    sets the thread-resources physical mesh) on older releases."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (its default is
+    ``Explicit``): the model code places activations with sharding hints
+    and leaves the rest to the partitioner."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh with the production axis names (CPU tests)."""
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:
@@ -48,5 +41,4 @@ def batch_axes(mesh) -> tuple:
 
 
 def mesh_devices(mesh) -> int:
-    import math
     return math.prod(mesh.shape.values())

@@ -26,17 +26,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import ops as kernel_ops
 from repro.models.layers import (apply_rope, dense_init, rope_table,
                                  seq_axis, seq_hint, shard_hint)
 
 NEG_INF = -1e30
-
-
-def _use_paged_kernel() -> bool:
-    """Route paged decode through the Pallas block-table kernel on TPU;
-    the CPU CI path uses the gather + masked-softmax reference instead
-    (interpret-mode Pallas would dominate test wall time)."""
-    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -623,8 +617,10 @@ def apply_attention(params: dict, x: jax.Array, *,
         # ring (SWA) layers are window-bounded and stay per-slot.
         assert cache is not None and window is None
         new_cache = paged_write(cache, k, v, block_tables, pos_arr)
-        if _use_paged_kernel() and (not tree or t_prev == 0):
-            from repro.kernels import ops as kernel_ops
+        # compiled block-table kernel on TPU; elsewhere the gather +
+        # masked-softmax reference (interpret-mode Pallas would dominate
+        # test wall time)
+        if kernel_ops.use_compiled_kernels() and (not tree or t_prev == 0):
             # full-buffer tree verify (prev == 0): the kernel masks the
             # last Sq rows with per-node int32 ancestor bitmasks
             anc = (jnp.asarray(np.asarray(spec_tree["anc_bits"]))

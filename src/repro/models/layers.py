@@ -44,18 +44,9 @@ def seq_axis():
 
 
 def ambient_mesh():
-    """The mesh of the enclosing ``with mesh:`` scope, or None.
-
-    ``jax.sharding.get_abstract_mesh`` only exists on newer jax; on older
-    releases fall back to the thread-resources physical mesh that the
-    ``Mesh`` context manager installs."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        mesh = get()
-    else:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-    if mesh is None or mesh.empty:
+    """The mesh installed by an enclosing ``jax.set_mesh`` scope, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return None
     return mesh
 
@@ -123,7 +114,10 @@ def dense_init(key, d_in: int, d_out: int, dtype) -> jax.Array:
 
 
 def embed_init(key, vocab: int, d_model: int, dtype) -> jax.Array:
-    return (jax.random.normal(key, (vocab, d_model)) * 0.02).astype(dtype)
+    # the barrier keeps XLA from folding the 0.02 into the sampler's own
+    # constants under jit, so jitted and eager init agree bit for bit
+    z = jax.lax.optimization_barrier(jax.random.normal(key, (vocab, d_model)))
+    return (z * 0.02).astype(dtype)
 
 
 # ---------------------------------------------------------------------------

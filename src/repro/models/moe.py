@@ -30,26 +30,6 @@ from jax.sharding import PartitionSpec as P
 from repro.models.layers import _act, dense_init
 
 
-def _axis_size(axis_name):
-    """``jax.lax.axis_size`` is newer jax; psum(1) is the portable way to
-    read a mapped axis' size inside shard_map."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the public ``jax.shard_map`` (with
-    ``check_vma``) is newer; older releases have the experimental one
-    (with ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # params
 
@@ -185,7 +165,7 @@ def _moe_ep_body(params, x_flat, *, n_experts, top_k, capacity_factor,
                  activation, model_axis="model"):
     """Per-chip body: tokens local shard, experts sharded on ``model``."""
     n = x_flat.shape[0]
-    msize = _axis_size(model_axis)
+    msize = jax.lax.axis_size(model_axis)
     cap = _capacity(n, top_k, n_experts, capacity_factor)
     idx, gate = _route(params["router"], x_flat, n_experts, top_k)
     buf, slot = _dispatch(x_flat, idx, n_experts, cap)       # (E, C, D)
@@ -213,7 +193,7 @@ def _moe_ep_psum_body(params, x_flat, *, n_experts, top_k, capacity_factor,
     layer; the GBs of expert weights never move.
     """
     n = x_flat.shape[0]                       # x_flat: (N, D_local)
-    msize = _axis_size(model_axis)
+    msize = jax.lax.axis_size(model_axis)
     e_loc = n_experts // msize
     cap = _capacity(n, top_k, n_experts, capacity_factor)
 
@@ -302,6 +282,6 @@ def apply_moe(params: dict, x: jax.Array, *, n_experts: int, top_k: int,
         out = body(p, xx.reshape(-1, xx.shape[-1]), **kw)
         return out.reshape(xx.shape)
 
-    return _shard_map(
-        shard_fn, mesh,
-        (_view_specs(activation, mode), x_spec), x_spec)(params, x)
+    return jax.shard_map(
+        shard_fn, mesh=mesh, in_specs=(_view_specs(activation, mode), x_spec),
+        out_specs=x_spec, check_vma=False)(params, x)

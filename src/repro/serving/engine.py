@@ -1218,6 +1218,18 @@ class ServingEngine:
         granted = sum(a.granted_total for a in self._allocs)
         return ks["bytes_per_block"] * granted / self._blocks_granted_seqs
 
+    def lower_fused(self):
+        """The next fused verify+draft round lowered for this engine's
+        slot shapes, without running it (see
+        :meth:`InterleavedPipeline.lower_fused`)."""
+        if self._halves is None:
+            raise ValueError("lower_fused() before any round was served")
+        v = self._v
+        pipe = self.engine.pipeline(self.config.n_cand,
+                                    tree=self.config.spec_tree)
+        pipe.warmup(self._halves[v])
+        return pipe.lower_fused(self._halves[v], self._halves[1 - v])
+
     def stats(self) -> dict:
         """Engine-level serving metrics."""
         pipe = self.engine._pipe

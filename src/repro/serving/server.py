@@ -36,6 +36,9 @@ Semantics:
 * **Draining** — :meth:`drain` stops admission (new submits are
   rejected), serves every queued/in-flight request to completion,
   flushes all streams, and stops the background task.
+* **Failure** — if a round raises (e.g. a device error), the loop
+  stops, every open stream and pending submit raises that error, and
+  :meth:`drain` re-raises it; nothing waits forever.
 
 Thread discipline: the engine is only ever touched from one logical
 context at a time.  ``submit()`` never calls into the engine directly —
@@ -98,6 +101,7 @@ class AsyncServingServer:
         self._rids = itertools.count()
         self._task: asyncio.Task | None = None
         self._draining = False
+        self._error: BaseException | None = None  # what stopped the loop
         self.completed: list[ServeRequest] = []
 
     # ------------------------------------------------------------------
@@ -148,6 +152,8 @@ class AsyncServingServer:
         if self._task is None and not self._draining:
             await self.start()    # a drained server needs explicit start()
         rid = next(self._rids) if rid is None else rid
+        if self._error is not None:
+            raise self._error
         if self._draining:
             raise RequestRejected("draining", rid)
         req = ServeRequest(rid, np.asarray(prompt, np.int32),
@@ -157,7 +163,8 @@ class AsyncServingServer:
         deadline = (None if self.submit_timeout_s is None
                     else time.monotonic() + self.submit_timeout_s)
         async with self._space:
-            while self._depth() >= self.max_queue and not self._draining:
+            while (self._depth() >= self.max_queue and not self._draining
+                   and self._error is None):
                 timeout = (None if deadline is None
                            else deadline - time.monotonic())
                 if timeout is not None and timeout <= 0:
@@ -167,6 +174,8 @@ class AsyncServingServer:
                                            timeout=timeout)
                 except asyncio.TimeoutError:
                     self._reject("backpressure_timeout", rid, tenant)
+            if self._error is not None:
+                raise self._error
             if self._draining:
                 raise RequestRejected("draining", rid)
         fut = asyncio.get_running_loop().create_future()
@@ -178,15 +187,18 @@ class AsyncServingServer:
 
     async def stream(self, req: ServeRequest):
         """Async-iterate the request's verified tokens as they retire;
-        ends (StopAsyncIteration) after the last token."""
+        ends (StopAsyncIteration) after the last token, or raises the
+        error that stopped the serve loop."""
         q = self._streams.get(req.rid)
         if q is None:
             return                    # already fully streamed
         while True:
             tok = await q.get()
-            if tok is None:
+            if tok is None or isinstance(tok, BaseException):
                 self._streams.pop(req.rid, None)
-                return
+                if tok is None:
+                    return
+                raise tok
             yield tok
 
     async def collect(self, req: ServeRequest) -> list:
@@ -195,7 +207,8 @@ class AsyncServingServer:
 
     async def drain(self):
         """Graceful shutdown: reject new submissions, serve everything
-        already queued or in flight, flush all streams, stop the loop."""
+        already queued or in flight, flush all streams, stop the loop.
+        Re-raises the error that stopped the loop, if one did."""
         self._draining = True
         self._wake.set()
         async with self._space:       # release backpressure waiters
@@ -204,6 +217,8 @@ class AsyncServingServer:
             await self._task
             self._task = None
         self.engine._close_window()   # seal the serving wall window
+        if self._error is not None:
+            raise self._error
 
     # ------------------------------------------------------------------
     def _drain_ingress(self):
@@ -242,7 +257,11 @@ class AsyncServingServer:
                 continue
             # one fused round off-thread: the event loop stays live for
             # submits/streams while the engine verifies+drafts
-            await asyncio.to_thread(eng.run_step)
+            try:
+                await asyncio.to_thread(eng.run_step)
+            except Exception as e:
+                await self._fail(e)
+                return
             self._flush_emissions()
             async with self._space:
                 self._space.notify_all()
@@ -252,6 +271,19 @@ class AsyncServingServer:
             else:
                 await asyncio.sleep(0)
         self._flush_emissions()
+
+    async def _fail(self, err: Exception):
+        """End every open stream and pending submit with ``err``."""
+        self._error = err
+        self._flush_emissions()
+        for q in self._streams.values():
+            q.put_nowait(err)
+        while self._ingress:
+            _, fut = self._ingress.popleft()
+            if not fut.done():
+                fut.set_exception(err)
+        async with self._space:       # release backpressure waiters
+            self._space.notify_all()
 
     # ------------------------------------------------------------------
     def tenant_report(self) -> dict:

@@ -120,6 +120,37 @@ def test_submit_after_drain_rejected():
     asyncio.run(drive())
 
 
+def test_round_error_fails_streams_and_submits():
+    """A round that raises (a device error, say) stops the serve loop:
+    the open stream, a later submit and drain() all raise that error
+    within a timeout instead of waiting forever."""
+    se = _engine()
+    real_step, calls = se.run_step, []
+
+    def failing_step():
+        calls.append(1)
+        if len(calls) > 2:
+            raise RuntimeError("device lost")
+        return real_step()
+
+    se.run_step = failing_step
+    prompt = np.arange(5, dtype=np.int32)
+
+    async def drive():
+        srv = AsyncServingServer(se, max_queue=8)
+        await srv.start()
+        h = await asyncio.wait_for(srv.submit(prompt, 20), timeout=30)
+        with pytest.raises(RuntimeError, match="device lost"):
+            await asyncio.wait_for(srv.collect(h), timeout=30)
+        with pytest.raises(RuntimeError, match="device lost"):
+            await asyncio.wait_for(srv.submit(prompt, 3), timeout=30)
+        with pytest.raises(RuntimeError, match="device lost"):
+            await asyncio.wait_for(srv.drain(), timeout=30)
+
+    asyncio.run(drive())
+    assert len(calls) == 3                        # no round after the error
+
+
 def test_weighted_fairness_two_tenants():
     """A flood from tenant A must not starve tenant B: with qos fair
     ordering, B's first admission beats A's backlog even though every

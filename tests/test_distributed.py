@@ -1,5 +1,6 @@
 """Distributed-correctness tests (run in subprocesses with 8 fake devices,
 since the main pytest process holds the 1-device CPU backend)."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -17,7 +18,8 @@ def _run(body: str):
     r = subprocess.run([sys.executable, "-c", script],
                        capture_output=True, text=True,
                        env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"},
+                            "HOME": os.environ.get("HOME", ""),
+                            "JAX_PLATFORMS": "cpu"},
                        timeout=560)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
     return r.stdout
@@ -27,8 +29,8 @@ def test_moe_distributed_modes_match_local():
     out = _run("""
         from repro.models.moe import (apply_moe, init_moe, _moe_local,
                                       select_moe_mode)
-        from repro.launch.mesh import activate_mesh, make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 4), ("data", "model"))
         E, D, F, topk = 8, 64, 96, 2
         p = init_moe(jax.random.PRNGKey(0), D, F, E, "swiglu", jnp.float32)
         for b, s, expect in [(4, 8, "ep"), (6, 1, "ep_psum")]:
@@ -36,7 +38,7 @@ def test_moe_distributed_modes_match_local():
             ref = _moe_local(p, x.reshape(-1, D), n_experts=E, top_k=topk,
                              capacity_factor=float("inf"),
                              activation="swiglu").reshape(b, s, D)
-            with activate_mesh(mesh):
+            with jax.set_mesh(mesh):
                 mode = select_moe_mode(E, s, mesh)
                 assert mode == expect, (mode, expect)
                 out = jax.jit(lambda pp, xx: apply_moe(
@@ -66,9 +68,9 @@ def test_sharded_decode_matches_single_device():
         nxt = jnp.argmax(lg, -1)[:, None]
         ref, _ = M.decode_step(p, cfg, cache, nxt)
 
-        from repro.launch.mesh import activate_mesh, make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
-        with activate_mesh(mesh):
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 4), ("data", "model"))
+        with jax.set_mesh(mesh):
             lg2, cache2 = jax.jit(M.prefill, static_argnums=(1,))(
                 p, cfg, toks, init_cache(cfg, 4, 24))
             got, _ = jax.jit(M.decode_step, static_argnums=(1,))(
@@ -97,10 +99,10 @@ def test_train_step_runs_under_mesh():
         st = oi(p)
         batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1),
                                               (8, 32), 0, 97)}
-        from repro.launch.mesh import activate_mesh, make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 4), ("data", "model"))
         step = make_train_step(cfg, mesh, 1e-3, accum_steps=2)
-        with activate_mesh(mesh):
+        with jax.set_mesh(mesh):
             def fn(pp, ss, bb):
                 with sequence_sharding("model"):
                     return step(pp, ss, bb)

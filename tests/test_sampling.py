@@ -1,4 +1,5 @@
 """Sampling-mode speculative decoding (Leviathan rule) and CLI launchers."""
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -63,7 +64,8 @@ def _cli(args):
     r = subprocess.run([sys.executable, "-m"] + args, capture_output=True,
                        text=True, timeout=560,
                        env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                            "HOME": os.environ.get("HOME", ""),
+                            "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-3000:]
     return r.stdout
 
