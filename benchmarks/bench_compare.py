@@ -8,9 +8,6 @@ an eyeballed JSON diff:
 * ``tok_per_s`` (traced + untraced) must stay above
   ``tol_throughput`` x baseline (default 0.35 — shared CI runners are
   noisy; the gate catches collapses, not jitter).
-* ``gpu_busy_frac`` (the paper's utilization metric, derived from the
-  span tracer's bubble accounting) must stay above ``tol_busy`` x
-  baseline (default 0.5).
 * TTFT p50/p95 must stay below ``tol_latency`` x baseline (default
   3.0).
 * ``untraced_fused_compiles`` must not exceed the baseline: a second
@@ -41,15 +38,12 @@ CHECKS = (
      "tol_throughput"),
     ("traced_tok_per_s", ("traced_tok_per_s",), "min_ratio",
      "tol_throughput"),
-    ("gpu_busy_frac", ("utilization", "gpu_busy_frac"), "min_ratio",
-     "tol_busy"),
     ("ttft_p50_s", ("ttft", "p50"), "max_ratio", "tol_latency"),
     ("ttft_p95_s", ("ttft", "p95"), "max_ratio", "tol_latency"),
     ("fused_compiles", ("untraced_fused_compiles",), "max_value", None),
 )
 
-DEFAULT_TOLERANCES = {"tol_throughput": 0.35, "tol_busy": 0.5,
-                      "tol_latency": 3.0}
+DEFAULT_TOLERANCES = {"tol_throughput": 0.35, "tol_latency": 3.0}
 
 
 def _lookup(digest: dict, path: tuple):
@@ -129,9 +123,6 @@ def main():
     ap.add_argument("--tol-throughput", type=float,
                     default=DEFAULT_TOLERANCES["tol_throughput"],
                     help="min tok/s ratio vs baseline")
-    ap.add_argument("--tol-busy", type=float,
-                    default=DEFAULT_TOLERANCES["tol_busy"],
-                    help="min GPU-busy-fraction ratio vs baseline")
     ap.add_argument("--tol-latency", type=float,
                     default=DEFAULT_TOLERANCES["tol_latency"],
                     help="max TTFT ratio vs baseline")
@@ -154,7 +145,6 @@ def main():
 
     report = compare_digests(baseline, current,
                              {"tol_throughput": args.tol_throughput,
-                              "tol_busy": args.tol_busy,
                               "tol_latency": args.tol_latency})
     print(f"bench_compare: {args.current} vs {args.baseline}")
     print_report(report)

@@ -16,8 +16,8 @@ benchmarks/run.py: ``(name, value, derived)``.
     # chain-vs-tree speculation A/B at equal candidate budget
     #   -> BENCH_serving_tree.json
     PYTHONPATH=src python -m benchmarks.serving_bench --compare-spec
-    # observability run: Perfetto trace + metrics snapshot + utilization
-    # digest (paper's bubble/GPU-busy metric) -> BENCH_serving_obs.json
+    # observability run: Perfetto trace + metrics snapshot + digest
+    #   -> BENCH_serving_obs.json
     PYTHONPATH=src python -m benchmarks.serving_bench \\
         --trace-out trace.json --metrics-out metrics.json
 """
@@ -329,10 +329,10 @@ def obs_run(requests: int = 10, gen: int = 8, rate: float = 2.0,
             seed: int = 0, trace_out: str | None = None,
             metrics_out: str | None = None) -> dict:
     """Observability benchmark: the same Poisson trace twice — once with
-    the span tracer on (utilization / bubble accounting, Perfetto trace,
-    metrics snapshot) and once with tracing disabled (throughput parity
-    + fused-compile baseline).  Returns the ``BENCH_serving_obs.json``
-    digest; writes the raw trace/metrics JSON when paths are given.
+    the span tracer on (Perfetto trace, metrics snapshot) and once with
+    tracing disabled (throughput parity + fused-compile baseline).
+    Returns the ``BENCH_serving_obs.json`` digest; writes the raw
+    trace/metrics JSON when paths are given.
     """
     import json
 
@@ -343,7 +343,6 @@ def obs_run(requests: int = 10, gen: int = 8, rate: float = 2.0,
                  trace=True, request_timeline=True)
     eng = traced["engine"]
     rep = eng.metrics()
-    util = rep["utilization"]
     if trace_out:
         with open(trace_out, "w") as f:
             json.dump(eng.chrome_trace(), f)
@@ -361,18 +360,6 @@ def obs_run(requests: int = 10, gen: int = 8, rate: float = 2.0,
                   "seed": seed,
                   "config": "MIXTRAL_8X7B.reduced(d_model=64) / "
                             "max_batch=2 x2, n_cand=2"},
-        "utilization": {
-            "rounds": util["rounds"],
-            "gpu_busy_frac": util["gpu_busy_frac"],
-            "mean_round_busy_frac": util["mean_round_busy_frac"],
-            "busy_s": util["busy_s"],
-            "stall_s": util["stall_s"],
-            "idle_s": util["idle_s"],
-            "per_round_busy_frac": [r["busy_frac"]
-                                    for r in util["per_round"]],
-            "per_round_stall_s": [r["stall_s"]
-                                  for r in util["per_round"]],
-        },
         "transfers": {
             "bytes_by_tier": snap["counters"].get(
                 "transfer_bytes_total", {}),
@@ -428,10 +415,10 @@ def main():
                     help="write a Perfetto-loadable Chrome trace JSON "
                          "(enables the observability run)")
     ap.add_argument("--metrics-out", default=None,
-                    help="write the metrics snapshot + utilization "
-                         "report JSON (enables the observability run)")
+                    help="write the metrics snapshot JSON (enables the "
+                         "observability run)")
     ap.add_argument("--obs-out", default="BENCH_serving_obs.json",
-                    help="utilization digest path for the obs run")
+                    help="digest path for the obs run")
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -462,13 +449,9 @@ def main():
                          metrics_out=args.metrics_out)
         with open(args.obs_out, "w") as f:
             json.dump(digest, f, indent=2)
-        u = digest["utilization"]
         print(f"wrote {args.obs_out}"
               + (f", {args.trace_out}" if args.trace_out else "")
               + (f", {args.metrics_out}" if args.metrics_out else ""))
-        print(f"GPU busy fraction: {u['gpu_busy_frac']:.2f} over "
-              f"{u['rounds']} rounds "
-              f"(stall {u['stall_s']:.2f}s, idle {u['idle_s']:.2f}s)")
         print(f"tok/s traced {digest['traced_tok_per_s']:.2f} vs "
               f"untraced {digest['untraced_tok_per_s']:.2f}; "
               f"fused compiles (untraced) "

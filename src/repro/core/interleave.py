@@ -66,10 +66,12 @@ class RoundOutput:
     tokens: np.ndarray           # (B, m+1) output slots (d_1..d_a, bonus, 0s)
     n_emitted: np.ndarray        # (B,) in [1, m+1]: valid prefix of tokens
     n_accept: np.ndarray         # (B,) accepted draft tokens this round
-    # wall interval of the whole fused round (perf_counter seconds),
-    # measured unconditionally (two clock reads) so request-scoped
-    # timelines can attribute decode time without the span tracer on
+    # perf_counter stamps of the round, taken unconditionally so request
+    # timelines and the engine's phase counters work without the span
+    # tracer: [t0, t_dispatched) dispatches the fused and rollback
+    # programs, [t_dispatched, t1) waits for and fetches the outputs
     t0: float = 0.0
+    t_dispatched: float = 0.0
     t1: float = 0.0
 
 
@@ -272,16 +274,10 @@ class InterleavedPipeline:
         assert gen.drafts is None, "gen batch already holds drafts"
         t_round0 = time.perf_counter()
         tr = self.obs.tracer
-        # The fused call is ONE XLA program doing both phases; record it
-        # as anti-phase twins — a verify span plus a mirrored draft span
-        # over the same interval (bubble accounting unions the overlap,
-        # so device-busy time is not double counted).
+        # one XLA program verifies batch V and drafts for batch D
         with tr.span("target_verify", "verify(fused)", cat="device") as sp:
             vout, dout = self._fused(*self._fused_args(verify, gen))
             sp.fence((vout, dout))
-        if tr.enabled:
-            tr.complete("draft_generate", "draft(fused)", sp.t0, sp.t1,
-                        cat="device")
         verify.target_cache = vout["target_cache"]
         if self.tree is not None:
             # batch V's draft cache was compacted to the accepted path
@@ -293,18 +289,26 @@ class InterleavedPipeline:
                 verify.draft_cache = rb.fence(self._rollback(
                     self.dcfg, verify.draft_cache, verify.draft_pendings,
                     vout["n_emitted"]))
+        t_dispatched = time.perf_counter()
         verify.t_next = vout["t_next"]
         verify.drafts, verify.draft_pendings = None, None
-        out = RoundOutput(tokens=np.asarray(vout["tokens"]),
-                          n_emitted=np.asarray(vout["n_emitted"]),
-                          n_accept=np.asarray(vout["n_accept"]),
-                          t0=t_round0, t1=time.perf_counter())
+        # Waits for the fused program, then copies its outputs to the
+        # host.  Batch D's fresh drafts are stashed, and the round's
+        # spent device arrays released, inside the span too: that host
+        # work runs while the device idles, so it belongs to this phase.
+        with tr.span("d2h", "outputs"):
+            tokens = np.asarray(vout["tokens"])
+            n_emitted = np.asarray(vout["n_emitted"])
+            n_accept = np.asarray(vout["n_accept"])
+            gen.drafts = dout["drafts"]
+            gen.draft_cache = dout["draft_cache"]
+            gen.draft_pendings = dout.get("pendings")
+            del vout, dout
+        out = RoundOutput(tokens=tokens, n_emitted=n_emitted,
+                          n_accept=n_accept, t0=t_round0,
+                          t_dispatched=t_dispatched, t1=time.perf_counter())
         if record:
             verify.emitted.append((out.tokens, out.n_emitted))
-        # batch D: stash fresh drafts
-        gen.drafts = dout["drafts"]
-        gen.draft_cache = dout["draft_cache"]
-        gen.draft_pendings = dout.get("pendings")
         return out
 
     def run(self, states: list, gen_len: int, max_rounds: int = 10_000):

@@ -64,8 +64,7 @@ def record_transfer(obs, tier: str, nbytes: float, seconds: float,
 
     ``tier`` names the link direction ("h2d", "d2h"); bytes and seconds
     feed the ``transfer_bytes_total`` / ``transfer_seconds_total``
-    counters the bench's utilization report reads, and a completed span
-    lands on the matching trace track.
+    counters, and a completed span lands on the matching trace track.
     """
     if not obs.enabled:
         return

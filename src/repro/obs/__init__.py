@@ -3,9 +3,8 @@
 One facade object (:class:`Obs`) bundles the two backbones every layer
 shares:
 
-* ``obs.tracer`` — span tracer exporting Chrome trace-event JSON
-  (:mod:`repro.obs.trace`), plus bubble accounting that derives the
-  paper's GPU-utilization metric from the recorded spans.
+* ``obs.tracer`` — span tracer exporting Chrome trace-event JSON and,
+  optionally, profiler annotations (:mod:`repro.obs.trace`).
 * ``obs.metrics`` — labeled Counter/Gauge/Histogram registry with JSON
   snapshot and Prometheus text exposition (:mod:`repro.obs.metrics`).
 
@@ -25,8 +24,7 @@ from repro.obs.request_trace import (NULL_REQUEST_TRACKER,  # noqa: F401
                                      timelines_summary)
 from repro.obs.slo import (SLO, FlightRecorder, SLOMonitor,  # noqa: F401
                            as_slos)
-from repro.obs.trace import (NULL_TRACER, NullTracer, Tracer,  # noqa: F401
-                             bubble_report)
+from repro.obs.trace import NULL_TRACER, NullTracer, Tracer  # noqa: F401
 
 
 @dataclass(frozen=True)

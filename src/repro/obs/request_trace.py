@@ -2,10 +2,10 @@
 stack.
 
 The round-level tracer (:mod:`repro.obs.trace`) measures the *engine* —
-GPU busy fraction, pipeline stall, the paper's utilization claim.  A
-serving stack is judged per *request*: time queued, time prefilling,
-time riding fused decode rounds, time parked by a preemption, TTFT and
-inter-token cadence per tenant.  This module attributes every phase of
+its rounds and the phases inside them.  A serving stack is judged per
+*request*: time queued, time prefilling, time riding fused decode
+rounds, time parked by a preemption, TTFT and inter-token cadence per
+tenant.  This module attributes every phase of
 a request's life to its request ID (minted in
 ``AsyncServingServer.submit()`` / ``ServingEngine.submit``):
 

@@ -1,9 +1,6 @@
 """Low-overhead span tracer for the serving pipeline.
 
-The paper's headline metric is *utilization* — how much of the offload
-bubble the interleaved draft fills (§5: 4.49x GPU core utilization).
-Measuring that needs per-phase wall time with device fencing, not
-end-of-run tokens/s.  This module provides:
+This module provides:
 
 * :class:`Tracer` — context-manager spans on named **tracks** (one per
   pipeline phase: ``target_verify``, ``draft_generate``, ``rollback``,
@@ -16,16 +13,14 @@ end-of-run tokens/s.  This module provides:
 * **Honest device timing** — JAX dispatch is asynchronous, so a span
   around a jitted call measures dispatch, not compute.  Inside a span,
   ``sp.fence(arrays)`` calls ``jax.block_until_ready`` before the span
-  closes (only when the tracer fences; a no-op otherwise), and the span
-  enters a ``jax.profiler.TraceAnnotation`` when available so the same
-  phase names show up in XLA profiler dumps.
+  closes (only when the tracer fences; a no-op otherwise).
+* **Spans on the device trace's clock** — with ``annotations`` each span
+  enters a ``jax.profiler.TraceAnnotation`` named ``<track>/<name>``, so
+  a profiler trace shows what the host was doing in every device idle
+  gap (``bench/xplane.py`` names each gap by the innermost one).
 * **Chrome trace-event export** — :meth:`Tracer.to_chrome_trace`
   returns the JSON object format (``{"traceEvents": [...]}``) loadable
   in Perfetto / ``chrome://tracing``, with one named thread per track.
-* :func:`bubble_report` — the paper's utilization metric, derived from
-  spans: per round, GPU busy fraction = union of device-category span
-  time inside the round / round wall time; pipeline stall (bubble) =
-  the remainder.
 
 Zero cost when disabled: :data:`NULL_TRACER` returns one shared no-op
 span object from every call — nothing is allocated per round (asserted
@@ -49,9 +44,6 @@ except Exception:  # pragma: no cover - obs must import without jax
 # Canonical pipeline tracks, in display order (Perfetto sorts by tid).
 TRACKS = ("round", "target_verify", "draft_generate", "rollback",
           "prefill", "h2d", "d2h", "kv", "admit", "planner")
-
-#: span categories that count as accelerator-busy for bubble accounting
-DEVICE_CATS = frozenset({"device"})
 
 
 class _NullSpan:
@@ -126,9 +118,11 @@ class _Span:
         if self._fence is not None and _HAS_JAX:
             _jax.block_until_ready(self._fence)
         self.t1 = time.perf_counter()
+        # record inside the annotation: the tracer's own host time then
+        # carries this span's name on the device trace
+        self._tr._record(self)
         if self._annot is not None:
             self._annot.__exit__(*exc)
-        self._tr._record(self)
         return False
 
     def fence(self, arrays):
@@ -163,9 +157,9 @@ class Tracer:
         self.events: list[dict] = []         # chrome trace events (us)
         self._tids: dict[str, int] = {}
         # Guards track creation only: event appends are GIL-atomic, and
-        # readers (to_chrome_trace / bubble accounting) take one atomic
-        # list() copy — the engine's worker thread can keep recording
-        # while the asyncio side exports mid-round.
+        # readers (to_chrome_trace) take one atomic list() copy — the
+        # engine's worker thread can keep recording while the asyncio
+        # side exports mid-round.
         self._lock = threading.Lock()
 
     # ------------------------------------------------------------------
@@ -213,8 +207,8 @@ class Tracer:
 
     def complete(self, track: str, name: str, t0: float, t1: float,
                  cat: str | None = None, args: dict | None = None):
-        """Record an already-timed interval (perf_counter seconds) — used
-        to mirror the fused step onto both anti-phase tracks."""
+        """Record an already-timed interval (perf_counter seconds), e.g.
+        a transfer timed by its caller or a request's phase."""
         ev = {"name": name, "ph": "X", "pid": 1, "tid": self._tid(track),
               "ts": self._us(t0), "dur": max(0.0, (t1 - t0) * 1e6)}
         if cat:
@@ -248,79 +242,3 @@ class Tracer:
                 "displayTimeUnit": "ms",
                 "otherData": {"producer": "repro.obs.trace",
                               "clock": "CLOCK_MONOTONIC (perf_counter)"}}
-
-
-# ---------------------------------------------------------------------------
-# bubble accounting: the paper's utilization metric, derived from spans
-
-
-def _union_s(intervals: list[tuple]) -> float:
-    """Total length of the union of (t0, t1) intervals, seconds."""
-    total, hi = 0.0, None
-    for a, b in sorted(intervals):
-        if hi is None or a > hi:
-            total += b - a
-            hi = b
-        elif b > hi:
-            total += b - hi
-            hi = b
-    return total
-
-
-def bubble_report(tracer, round_track: str = "round",
-                  round_name: str = "round") -> dict:
-    """Per-round GPU busy fraction + pipeline-stall (bubble) accounting.
-
-    A *round* is one ``round_name`` span on ``round_track`` (one
-    scheduler iteration: admit -> fused verify+draft -> retire).  Busy
-    time is the union of device-category spans overlapping the round
-    (union, so the verify/draft anti-phase mirrors of the one fused XLA
-    program are not double counted); the stall is the remainder — host
-    scheduling, Python bookkeeping, un-overlapped transfers.  ``idle``
-    spans (empty engine waiting for arrivals) are excluded from stall
-    and summed separately.
-
-    Returns ``{"rounds", "per_round": [{busy_s, stall_s, busy_frac,
-    dur_s}...], "busy_s", "stall_s", "idle_s", "wall_s",
-    "gpu_busy_frac", "mean_round_busy_frac"}``.
-    """
-    rounds, idle_s, device = [], 0.0, []
-    for ev in list(tracer.events):   # atomic copy: recorder may append
-        if ev.get("ph") != "X":
-            continue
-        t0 = ev["ts"] * 1e-6
-        t1 = t0 + ev["dur"] * 1e-6
-        track = tracer_track_name(tracer, ev["tid"])
-        if track == round_track:
-            if ev["name"] == round_name:
-                rounds.append((t0, t1))
-            elif ev["name"] == "idle":
-                idle_s += t1 - t0
-        elif ev.get("cat") in DEVICE_CATS:
-            device.append((t0, t1))
-    per_round = []
-    for (r0, r1) in rounds:
-        inside = [(max(a, r0), min(b, r1)) for a, b in device
-                  if b > r0 and a < r1]
-        busy = _union_s(inside)
-        dur = r1 - r0
-        per_round.append({"dur_s": dur, "busy_s": busy,
-                          "stall_s": max(0.0, dur - busy),
-                          "busy_frac": busy / dur if dur > 0 else 0.0})
-    wall = sum(r["dur_s"] for r in per_round)
-    busy = sum(r["busy_s"] for r in per_round)
-    stall = sum(r["stall_s"] for r in per_round)
-    return {"rounds": len(per_round), "per_round": per_round,
-            "busy_s": busy, "stall_s": stall, "idle_s": idle_s,
-            "wall_s": wall,
-            "gpu_busy_frac": busy / wall if wall > 0 else 0.0,
-            "mean_round_busy_frac":
-                (sum(r["busy_frac"] for r in per_round) / len(per_round))
-                if per_round else 0.0}
-
-
-def tracer_track_name(tracer, tid: int) -> str | None:
-    for name, t in list(tracer._tids.items()):
-        if t == tid:
-            return name
-    return None

@@ -53,7 +53,9 @@ keeping the greedy stream lossless).
 """
 from __future__ import annotations
 
+import statistics
 import time
+from collections import deque
 from dataclasses import asdict, dataclass, field
 
 import jax
@@ -71,8 +73,7 @@ from repro.core.spec_decode import (record_acceptance, tree_n_nodes,
 from repro.models.transformer import (admit_sequence_paged, init_cache,
                                       init_paged_cache, release_slot_paged)
 from repro.obs import (NULL_REQUEST_TRACKER, FlightRecorder,
-                       RequestTracker, SLOMonitor, as_slos, bubble_report,
-                       make_obs)
+                       RequestTracker, SLOMonitor, as_slos, make_obs)
 from repro.obs.metrics import LATENCY_BUCKETS
 from repro.serving.paged_kv import BlockAllocator, prefix_block_keys
 from repro.sim.hardware import ENV1, HardwareSpec
@@ -194,10 +195,10 @@ class SchedulerConfig:
     metrics: bool = True          # labeled counter/gauge/histogram registry
                                   # behind ServingEngine.metrics(); cheap,
                                   # on by default
-    trace: bool = False           # span tracer -> Chrome trace JSON +
-                                  # bubble/utilization accounting.  Off by
-                                  # default: fencing serializes dispatch to
-                                  # get honest per-phase device timing
+    trace: bool = False           # span tracer -> Chrome trace JSON.
+                                  # Off by default: fencing serializes
+                                  # dispatch to get honest per-phase
+                                  # device timing
     trace_fence: bool = True      # block_until_ready at device-span exit
     trace_annotations: bool = False  # jax.profiler.TraceAnnotation per span
     # ---- request-scoped observability + SLOs (repro.obs) ----
@@ -231,6 +232,90 @@ class _Slot:
     accept_ema: float = 0.7       # EMA of this sequence's per-round
                                   # acceptance fraction (accepted depth /
                                   # depth budget); feeds replanning
+
+
+#: rounds of history whose median sets a phase's stall threshold
+STALL_WINDOW = 64
+#: rounds of history before a phase is judged at all
+STALL_MIN_HISTORY = 16
+#: a phase that takes over this many times that median is a stall
+STALL_FACTOR = 4.0
+
+
+class _RoundPhases:
+    """Counters of where the host's time goes around each fused round.
+
+    Dispatch is ``RoundOutput.t0`` -> ``t_dispatched`` (the fused and
+    rollback dispatch calls), fetch is ``t_dispatched`` -> ``t1`` (waiting
+    for the device and copying the outputs), host serial work is one
+    round's ``t1`` -> the next round's ``t0`` less the admissions in
+    between, which are counted on their own.  A phase that takes over
+    ``STALL_FACTOR`` times its median over the previous ``STALL_WINDOW``
+    rounds (or as many as there are, once ``STALL_MIN_HISTORY``) is a
+    stall: its excess over that median goes to the phase's stall
+    counter.  The counters are unlabeled, so a reader that sums a
+    counter over its label sets reads them as they are.
+    """
+    PHASES = ("dispatch", "fetch", "host")
+
+    def __init__(self, reg):
+        self.rounds = reg.counter(
+            "serve_fused_rounds_total",
+            "scheduler rounds that dispatched the fused program")
+        self.seconds = {
+            "dispatch": reg.counter(
+                "serve_dispatch_seconds_total",
+                "host seconds in the fused and rollback dispatch calls"),
+            "fetch": reg.counter(
+                "serve_fetch_seconds_total",
+                "host seconds waiting for and fetching round outputs"),
+            "host": reg.counter(
+                "serve_host_serial_seconds_total",
+                "host seconds from a round's outputs to the next round's "
+                "dispatch, admissions excluded")}
+        self.admit = reg.counter(
+            "serve_admit_seconds_total",
+            "host seconds admitting requests (prefill + splice); the "
+            "fourth host phase, beside dispatch, fetch and host serial")
+        self.stall = {p: reg.counter(
+            f"serve_stall_{p}_seconds_total",
+            f"seconds of {p} phases beyond their median, over phases that "
+            f"took over {STALL_FACTOR:g}x it") for p in self.PHASES}
+        for c in (self.rounds, self.admit, *self.seconds.values(),
+                  *self.stall.values()):
+            c.inc(0.0)                # a run without stalls reads 0
+        self._hist = {p: deque(maxlen=STALL_WINDOW) for p in self.PHASES}
+        self._t1 = None               # last round's t1; None after idling
+        self._admit_s = 0.0           # admission seconds since then
+
+    def admitted(self, seconds: float):
+        self.admit.inc(seconds)
+        self._admit_s += seconds
+
+    def idle(self):
+        """The engine ran dry: the next round starts no host phase."""
+        self._t1 = None
+
+    def on_round(self, out) -> tuple:
+        """Count round ``out``; returns ``(phases, stalls)``: phase ->
+        seconds, and ``(phase, seconds, median)`` of each stalled one."""
+        phases = {"dispatch": out.t_dispatched - out.t0,
+                  "fetch": out.t1 - out.t_dispatched}
+        if self._t1 is not None:
+            phases["host"] = max(0.0, out.t0 - self._t1 - self._admit_s)
+        self._t1, self._admit_s = out.t1, 0.0
+        self.rounds.inc()
+        stalls = []
+        for p, dt in phases.items():
+            self.seconds[p].inc(dt)
+            hist = self._hist[p]
+            if len(hist) >= STALL_MIN_HISTORY:
+                med = statistics.median(hist)
+                if dt > STALL_FACTOR * med:
+                    self.stall[p].inc(dt - med)
+                    stalls.append((p, dt, med))
+            hist.append(dt)
+        return phases, stalls
 
 
 def latency_percentiles(done: list, attr: str = "latency_s",
@@ -296,6 +381,8 @@ class ServingEngine:
                 out_dir=cfg.postmortem_dir,
                 cooldown_s=cfg.postmortem_cooldown_s,
                 max_bundles=cfg.postmortem_max_bundles)
+        self._phases = (_RoundPhases(self.obs.metrics)
+                        if self.obs.metrics.enabled else None)
         self.slo_monitor = (SLOMonitor(self._slos,
                                        metrics=self.obs.metrics,
                                        tracer=self.obs.tracer,
@@ -604,7 +691,6 @@ class ServingEngine:
                 req.admitted_run = len(self._windows)
             if cfg.qos:
                 self._charge_tenant(req, len(prompt))
-            t_wall = time.time()
             pt0 = time.perf_counter()
             with self.obs.tracer.span("admit", "admit") as asp:
                 st = self.engine.prefill_batch(prompt[None, :],
@@ -632,8 +718,10 @@ class ServingEngine:
             t0 = int(np.asarray(st.t_next)[0])
             half.t_next = half.t_next.at[slot_idx].set(t0)
             pt1 = time.perf_counter()
-            dt = time.time() - t_wall
+            dt = pt1 - pt0
             self._tick(dt)
+            if self._phases is not None:
+                self._phases.admitted(dt)
             # resumed iff first token already produced (re-admission
             # after a preemption); closes the park interval as queue or
             # preempted time on the request's timeline
@@ -647,10 +735,6 @@ class ServingEngine:
                     + kv_bytes_per_token(self.draft_cfg))
                 record_transfer(self.obs, "h2d", kv_bytes, dt,
                                 what="kv_splice")
-                self.obs.metrics.histogram(
-                    "admit_seconds",
-                    "wall seconds per admission (prefill + splice)"
-                ).observe(dt)
                 self.obs.tracer.instant(
                     "admit", "admitted",
                     {"rid": req.rid, "half": h, "slot": slot_idx,
@@ -907,10 +991,10 @@ class ServingEngine:
         completed = []
         v = self._v
         # One "round" span per scheduler iteration (admit -> fused
-        # verify+draft -> retire); renamed "idle" when the engine is
-        # empty and only fast-forwards the clock, so bubble accounting
-        # never counts waiting-for-arrivals as stall.
-        with self.obs.tracer.span("round", "round") as rs:
+        # verify+draft -> account -> retire -> record); renamed "idle"
+        # when the engine is empty and only fast-forwards the clock.
+        tr = self.obs.tracer
+        with tr.span("round", "round") as rs:
             # slot surgery is legal on any half without staged drafts
             for h in (v, 1 - v):
                 if self._halves[h].drafts is None:
@@ -920,6 +1004,8 @@ class ServingEngine:
             if not self.has_live():
                 rs.rename("idle")
                 self.idle_step = True
+                if self._phases is not None:
+                    self._phases.idle()
                 if self._queue and not self._real_clock:
                     # fast-forward the virtual clock to the next arrival
                     self._now = max(self._now,
@@ -935,51 +1021,76 @@ class ServingEngine:
                                            self._halves[1 - v],
                                            cfg.n_cand, record=False,
                                            tree=cfg.spec_tree)
-            self._tick(time.time() - t_wall)
-            self._rounds += 1
-            self._record_occupancy()
-            self._record_acceptance_ema(v, out)
-            if self.obs.metrics.enabled:
-                self._round_metrics(out, live_v)
-            if self.requests.enabled:
-                # attribute the fused round to every live request BEFORE
-                # retirement pops slots: the verified half may have
-                # emitted tokens, the anti-phase half got fresh drafts —
-                # both are pipeline work done on the request's behalf
-                rd = self._rounds - 1
-                for idx, slot in enumerate(self._slots[v]):
-                    if not slot.done:
-                        self.requests.on_round(
-                            slot.req, rd, out.t0, out.t1,
-                            accepted=int(out.n_accept[idx]),
-                            emitted=int(out.n_emitted[idx]), role="verify")
-                for slot in self._slots[1 - v]:
-                    if not slot.done:
-                        self.requests.on_round(slot.req, rd, out.t0,
-                                               out.t1, role="draft")
-            completed += self._process_emissions(v, out)
-            self._maybe_replan()
+            with tr.span("round", "account"):
+                self._tick(time.time() - t_wall)
+                phases, stalls = self._account_round(v, out, live_v)
+            with tr.span("round", "emit"):
+                completed += self._process_emissions(v, out)
+            if self.recorder is not None:
+                with tr.span("round", "record"):
+                    self._record_round(out, t_step0, phases, stalls)
             self._v = 1 - v
         dt = time.time() - t_step0
         self._wall_s += dt
         self._open_window_s += dt
-        if self.recorder is not None:
-            # black box: one small record per round + anomaly detectors
-            # (works without the span tracer — busy fraction is the
-            # fused interval over the round's wall time)
-            busy_frac = max(0.0, out.t1 - out.t0) / max(dt, 1e-9)
-            self.recorder.record_round(
-                {"round": self._rounds - 1, "t0": out.t0, "t1": out.t1,
-                 "dur_s": dt, "busy_frac": busy_frac,
-                 "queue_depth": len(self._queue),
-                 "accept_mean": self._accept_last,
-                 "tokens_out": self._tokens_out})
-            hit = self.recorder.check(accept_mean=self._accept_last,
-                                      busy_frac=busy_frac,
-                                      queue_depth=len(self._queue))
-            if hit is not None:
-                self._postmortem(*hit)
         return completed
+
+    def _account_round(self, v: int, out, live_v: list | None):
+        """Post-round bookkeeping before retirement: occupancy,
+        acceptance, metrics, request timelines (while the retiring
+        slots still hold their requests), phase counters and
+        replanning.  Returns the phase counters' ``(phases, stalls)``."""
+        self._rounds += 1
+        self._record_occupancy()
+        self._record_acceptance_ema(v, out)
+        if self.obs.metrics.enabled:
+            self._round_metrics(out, live_v)
+        if self.requests.enabled:
+            # attribute the fused round to every live request: the
+            # verified half may have emitted tokens, the anti-phase half
+            # got fresh drafts — both are pipeline work done on the
+            # request's behalf
+            rd = self._rounds - 1
+            for idx, slot in enumerate(self._slots[v]):
+                if not slot.done:
+                    self.requests.on_round(
+                        slot.req, rd, out.t0, out.t1,
+                        accepted=int(out.n_accept[idx]),
+                        emitted=int(out.n_emitted[idx]), role="verify")
+            for slot in self._slots[1 - v]:
+                if not slot.done:
+                    self.requests.on_round(slot.req, rd, out.t0,
+                                           out.t1, role="draft")
+        self._maybe_replan()
+        if self._phases is None:
+            return None, ()
+        return self._phases.on_round(out)
+
+    def _record_round(self, out, t_step0: float, phases: dict | None,
+                      stalls):
+        """Flight recorder: one small record per round, after retirement
+        so it covers the whole round, then a ``stall`` trigger per
+        stalled phase and the anomaly detectors.  Works without the span
+        tracer: the busy fraction is the fused interval over the round's
+        wall time."""
+        dt = time.time() - t_step0
+        busy_frac = max(0.0, out.t1 - out.t0) / max(dt, 1e-9)
+        rec = {"round": self._rounds - 1, "t0": out.t0, "t1": out.t1,
+               "dur_s": dt, "busy_frac": busy_frac,
+               "queue_depth": len(self._queue),
+               "accept_mean": self._accept_last,
+               "tokens_out": self._tokens_out}
+        if phases:
+            rec.update((f"{p}_s", sec) for p, sec in phases.items())
+        self.recorder.record_round(rec)
+        for phase, sec, med in stalls:
+            self._postmortem("stall", {"phase": phase, "seconds": sec,
+                                       "median_s": med})
+        hit = self.recorder.check(accept_mean=self._accept_last,
+                                  busy_frac=busy_frac,
+                                  queue_depth=len(self._queue))
+        if hit is not None:
+            self._postmortem(*hit)
 
     def run(self, max_rounds: int = 100_000) -> list:
         """Serve until the queue and all in-flight sequences drain.
@@ -1060,19 +1171,11 @@ class ServingEngine:
                       len(self.replan_events))
 
     def metrics(self) -> dict:
-        """Structured observability snapshot.
-
-        ``{"metrics": <registry snapshot>}`` plus, when tracing is on,
-        ``"utilization"`` — the bubble-accounting report derived from
-        the recorded spans: per-round GPU busy fraction, total pipeline
-        stall (the paper's offload bubble), and idle time.  Use
-        ``prometheus()`` for the text exposition of the same registry.
-        """
+        """Structured observability snapshot: ``{"metrics": <registry
+        snapshot>}``.  Use ``prometheus()`` for the text exposition of
+        the same registry."""
         self._sync_metrics()
-        rep = {"metrics": self.obs.metrics.snapshot()}
-        if self.obs.tracer.enabled:
-            rep["utilization"] = bubble_report(self.obs.tracer)
-        return rep
+        return {"metrics": self.obs.metrics.snapshot()}
 
     def prometheus(self) -> str:
         """Prometheus text exposition of the metrics registry."""

@@ -1,6 +1,8 @@
 """Observability subsystem: Chrome-trace schema, Prometheus round trip,
-histogram percentiles, bubble accounting, zero-cost disabled mode, and
-the metrics-path regression that a serving run reports fused == 1."""
+histogram percentiles, the spans and phase counters of each fused round,
+zero-cost disabled mode, and the metrics-path regression that a serving
+run reports fused == 1."""
+import time
 import tracemalloc
 
 import numpy as np
@@ -10,7 +12,7 @@ from repro.obs import NULL_OBS, Obs, make_obs
 from repro.obs.metrics import (NULL_REGISTRY, Registry, acceptance_buckets)
 from repro.obs.schema import (parse_prometheus_text, validate_chrome_trace,
                               validate_metrics_snapshot)
-from repro.obs.trace import NULL_TRACER, Tracer, bubble_report
+from repro.obs.trace import NULL_TRACER, Tracer
 from repro.serving.engine import SchedulerConfig, ServeRequest, ServingEngine
 
 from conftest import tiny_config, tiny_draft_config
@@ -68,14 +70,10 @@ def test_trace_ts_dur_sane(traced):
     assert evs
     for e in evs:
         assert e["ts"] >= 0 and e["dur"] >= 0
-    # the anti-phase twins: each fused verify span has a draft mirror
-    # covering exactly the same interval
+    # the fused program is one span a round, on the target_verify track
     verify = [e for e in evs if e["name"] == "verify(fused)"]
-    draft = [e for e in evs if e["name"] == "draft(fused)"]
-    assert len(verify) == len(draft) > 0
-    for ve, de in zip(verify, draft):
-        assert ve["ts"] == pytest.approx(de["ts"], abs=1.0)
-        assert ve["dur"] == pytest.approx(de["dur"], abs=1.0)
+    assert len(verify) == se.stats()["rounds"]
+    assert not [e for e in evs if e["name"] == "draft(fused)"]
 
 
 def test_virtual_clock_stamped(traced):
@@ -87,23 +85,178 @@ def test_virtual_clock_stamped(traced):
 
 
 # ---------------------------------------------------------------------------
-# bubble accounting (the paper's utilization metric)
+# the fused round from inside the engine: spans and phase counters
 
 
-def test_bubble_report_consistency(traced):
+def _spans(trace: dict) -> list:
+    """(track, name, start us, end us) of every complete event."""
+    evs = trace["traceEvents"]
+    tracks = {e["tid"]: e["args"]["name"] for e in evs
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    return [(tracks[e["tid"]], e["name"], e["ts"], e["ts"] + e["dur"])
+            for e in evs if e["ph"] == "X"]
+
+
+def test_round_span_holds_each_phase_once(traced):
     se, _, _ = traced
-    rep = se.metrics()
-    util = rep["utilization"]
-    assert util["rounds"] == se.stats()["rounds"]
-    assert len(util["per_round"]) == util["rounds"]
-    for r in util["per_round"]:
-        assert 0.0 <= r["busy_frac"] <= 1.0
-        assert r["busy_s"] + r["stall_s"] == pytest.approx(r["dur_s"],
-                                                           rel=1e-6)
-    assert util["busy_s"] + util["stall_s"] == pytest.approx(
-        util["wall_s"], rel=1e-6)
-    assert 0.0 < util["gpu_busy_frac"] <= 1.0
-    assert util["stall_s"] >= 0.0
+    spans = _spans(se.chrome_trace())
+    rounds = [(a, b) for tr, n, a, b in spans
+              if (tr, n) == ("round", "round")]
+    assert len(rounds) == se.stats()["rounds"]
+    for a, b in rounds:
+        inside = [(tr, n) for tr, n, s0, s1 in spans
+                  if a <= s0 and s1 <= b and (tr, n) != ("round", "round")]
+        for phase in (("d2h", "outputs"), ("round", "emit"),
+                      ("round", "account")):
+            assert inside.count(phase) == 1, (phase, inside)
+
+
+def _long_engine(n_req: int = 12, **cfg):
+    se = ServingEngine(tiny_config(("attn",)), tiny_draft_config(),
+                       config=SchedulerConfig(max_batch=2, n_cand=2,
+                                              **cfg))
+    se.init_from_seed(0)
+    rng = np.random.default_rng(1)
+    for i in range(n_req):
+        se.submit(ServeRequest(i, rng.integers(1, 61, 8).astype(np.int32),
+                               max_new_tokens=int(rng.integers(8, 24))))
+    return se
+
+
+def _counter(se, name: str) -> float:
+    return se.metrics()["metrics"]["counters"][name][""]
+
+
+def test_phase_seconds_add_up_to_round_intervals():
+    """Round k's start minus round k-1's is round k-1's dispatch and
+    fetch, then the host serial work and admissions before round k."""
+    se = _long_engine()
+    se.run_step()                     # compiles; admissions before round 1
+    admit, rounds = se._phases.admit, []
+    while se.has_work():
+        a0 = admit.value()
+        se.run_step()
+        if not se.idle_step:
+            rounds.append((se.recorder.ring[-1], admit.value() - a0))
+    assert len(rounds) > 20
+    assert sum(a for _, a in rounds) > 0      # admissions mid-run
+    intervals = phases = 0.0
+    for (prev, _), (rec, adm) in zip(rounds, rounds[1:]):
+        intervals += rec["t0"] - prev["t0"]
+        phases += prev["dispatch_s"] + prev["fetch_s"] + rec["host_s"] + adm
+    assert phases == pytest.approx(intervals, rel=0.01)
+    assert _counter(se, "serve_fused_rounds_total") == se.stats()["rounds"]
+
+
+def test_host_serial_excludes_admission():
+    se = _long_engine()
+    se.run_step()
+    prefill = se.engine.prefill_batch
+
+    def slow_prefill(*args, **kwargs):
+        time.sleep(0.05)
+        return prefill(*args, **kwargs)
+
+    se.engine.prefill_batch = slow_prefill
+    a0, h0 = _counter(se, "serve_admit_seconds_total"), \
+        _counter(se, "serve_host_serial_seconds_total")
+    n0 = se.stats()["rounds"]
+    se.run()
+    admitted = _counter(se, "serve_admit_seconds_total") - a0
+    host = _counter(se, "serve_host_serial_seconds_total") - h0
+    assert admitted >= 0.05 * 8               # every later admission
+    assert host < 0.05 * (se.stats()["rounds"] - n0) / 4
+    assert max(r["host_s"] for r in se.recorder.ring if "host_s" in r) < 0.05
+
+
+class _SlowArray:
+    """A device output whose copy to the host takes 0.2 s longer."""
+
+    def __init__(self, x):
+        self.x = x
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(0.2)
+        return np.asarray(self.x, dtype)
+
+
+@pytest.mark.parametrize("phase", ["dispatch", "fetch", "host"])
+def test_stall_counted_in_its_phase(phase):
+    """A 0.2 s sleep once, after the stall rule has 64 rounds of
+    history, moves that phase's stall counter alone and raises a
+    ``stall`` trigger in the flight recorder."""
+    se = _long_engine(n_req=24)
+    pipe = se.engine.pipeline(se.config.n_cand)
+    fused, armed = pipe._fused, [False]
+
+    def due():
+        fire, armed[0] = armed[0], False
+        return fire
+
+    def slow_fused(*args, **kwargs):
+        if phase == "dispatch" and due():
+            time.sleep(0.2)
+        vout, dout = fused(*args, **kwargs)
+        if phase == "fetch" and due():
+            vout = dict(vout, tokens=_SlowArray(vout["tokens"]))
+        return vout, dout
+
+    def emit_hook(req, tok):
+        if phase == "host" and due():
+            time.sleep(0.2)
+
+    def stalls():
+        return {p: _counter(se, f"serve_stall_{p}_seconds_total")
+                for p in ("dispatch", "fetch", "host")}
+
+    pipe._fused, se.emit_hook = slow_fused, emit_hook
+    while se.has_work() and se.stats()["rounds"] < 70:
+        se.run_step()
+    before, n_triggers, armed[0] = stalls(), len(se.recorder.triggers), True
+    # a sleep in round 71's emission is host time before round 72
+    while se.has_work() and se.stats()["rounds"] < 73:
+        se.run_step()
+    assert not armed[0]
+    moved = {p: v - before[p] for p, v in stalls().items()}
+    assert moved.pop(phase) > 0.15
+    assert all(v < 0.05 for v in moved.values()), moved
+    assert any(t["reason"] == "stall" and t["args"]["phase"] == phase
+               for t in se.recorder.triggers[n_triggers:])
+
+
+def test_stall_counters_start_at_zero():
+    """An engine exports its stall counters at 0 before any round, so a
+    run without stalls reads 0 rather than a missing counter."""
+    counters = _long_engine(n_req=1).metrics()["metrics"]["counters"]
+    for p in ("dispatch", "fetch", "host"):
+        assert counters[f"serve_stall_{p}_seconds_total"][""] == 0.0
+
+
+def test_round_record_covers_retirement():
+    """The flight recorder's round record is taken after retirement: its
+    ``dur_s`` holds a slow emit hook, and its ``tokens_out`` counts the
+    requests that finished in that round."""
+    se = _long_engine()
+    slow = [False]
+
+    def emit_hook(req, tok):
+        if slow[0]:
+            slow[0] = False
+            time.sleep(0.1)
+
+    se.emit_hook = emit_hook
+    se.run_step()                     # compiles
+    slow[0] = True
+    se.run_step()
+    assert not slow[0]
+    assert se.recorder.ring[-1]["dur_s"] >= 0.1
+    finished = 0
+    while se.has_work() and not finished:
+        before = se.stats()["tokens_out"]
+        se.run_step()
+        finished = se.stats()["tokens_out"] - before
+    assert finished > 0
+    assert se.recorder.ring[-1]["tokens_out"] == se.stats()["tokens_out"]
 
 
 def test_tracing_does_not_retrace_fused(traced):
@@ -145,8 +298,9 @@ def test_fused_compiles_once_via_metrics_registry():
     ctr = snap["counters"]["pipeline_traces_total"]
     assert ctr['{entry="fused"}'] == 1
     assert ctr['{entry="rollback"}'] == 1
-    # trace-off mode records no spans and no utilization report
-    assert "utilization" not in se.metrics()
+    assert (snap["counters"]["serve_fused_rounds_total"][""]
+            == se.stats()["rounds"])
+    # trace-off mode records no spans
     assert se.chrome_trace()["traceEvents"] == []
 
 
@@ -301,6 +455,12 @@ def _null_round(tr, reg):
     with tr.span("round", "round") as sp:
         sp.fence(None)
         sp.set("k", 1)
+        with tr.span("d2h", "outputs"):
+            pass
+        with tr.span("round", "account"):
+            pass
+        with tr.span("round", "emit"):
+            pass
         sp.rename("idle")
     tr.instant("admit", "admitted")
     tr.complete("draft_generate", "d", 0.0, 1.0, cat="device")
@@ -339,34 +499,11 @@ def test_disabled_tracing_no_retained_allocations():
     tracemalloc.stop()
     assert grown_live > 100 * 1024, "sanity: live tracer retains events"
 
-
-# ---------------------------------------------------------------------------
-# bubble accounting on synthetic spans (unit-level)
-
-
-def test_bubble_union_does_not_double_count():
-    tr = Tracer(fence=False)
-    with tr.span("round", "round"):
-        with tr.span("target_verify", "v", cat="device") as sp:
-            pass
-    # mirror the same interval on the draft track (anti-phase twin)
-    tr.complete("draft_generate", "d", sp.t0, sp.t1, cat="device")
-    rep = bubble_report(tr)
-    assert rep["rounds"] == 1
-    # overlapped twins count once: busy <= round duration
-    assert rep["per_round"][0]["busy_s"] <= rep["per_round"][0]["dur_s"]
-
-
-def test_bubble_idle_rounds_excluded():
-    tr = Tracer(fence=False)
-    with tr.span("round", "idle"):
-        pass
-    with tr.span("round", "round"):
-        with tr.span("prefill", "p", cat="device"):
-            pass
-    rep = bubble_report(tr)
-    assert rep["rounds"] == 1
-    assert rep["idle_s"] >= 0.0
+    # with the registry off the engine keeps no phase counters either
+    off = ServingEngine(tiny_config(("attn",)), tiny_draft_config(),
+                        config=SchedulerConfig(max_batch=2, metrics=False))
+    assert off.obs is NULL_OBS
+    assert off._phases is None and off.recorder is None
 
 
 def test_make_obs_modes():

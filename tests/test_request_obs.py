@@ -7,7 +7,7 @@ The load-bearing guarantees:
 * tracking is host-side only — traced and untraced runs stay
   token-identical with exactly one fused compile;
 * per-request decode spans land inside the engine's round spans (the
-  request view and PR 7's bubble view describe the same pipeline);
+  request view and the engine's round view describe one pipeline);
 * a tight TTFT SLO on a two-tenant open-loop trace dumps exactly ONE
   schema-valid postmortem bundle (cooldown collapses the storm);
 * bench_compare passes on the committed baseline and fails on a
@@ -102,7 +102,7 @@ def test_per_request_tracks_in_chrome_trace(tracked):
 
 
 def test_request_decode_spans_inside_round_spans(tracked):
-    """The request view and the bubble/round view describe one pipeline:
+    """The request view and the round view describe one pipeline:
     each per-request verify span must lie inside some round span."""
     se, _ = tracked
     evs = se.chrome_trace()["traceEvents"]
@@ -352,7 +352,6 @@ def _baseline_digest():
     return {
         "untraced_tok_per_s": 10.0, "traced_tok_per_s": 5.0,
         "untraced_fused_compiles": 1,
-        "utilization": {"gpu_busy_frac": 0.9},
         "ttft": {"p50": 1.0, "p95": 2.0},
     }
 
