@@ -7,7 +7,9 @@ refuses fails here, with no chip.  The topology is described inside a
 module fixture (only one process may load the TPU compiler), and the
 tests skip where it cannot be described.
 """
+import math
 import os
+import re
 from functools import partial
 
 import jax
@@ -51,25 +53,36 @@ def _on(sharding, tree):
         tree)
 
 
-@pytest.mark.parametrize("case", ["chain", "tree", "int8"])
-def test_paged_kernel_compiles_at_published_widths(one_chip, case):
-    tree = case == "tree"
-    m = 10 if tree else N_CAND + 1                  # (3, 2) tree: 10 nodes
-    mbs = MAX_LEN // BLOCK
-    nb = 1 + BATCH * mbs
+def _pool_relayouts(hlo: str, pool_elems: int) -> list:
+    """The compiled program's copies, transposes and fusions whose result
+    holds as many elements as a KV pool: a relayout of the pool."""
+    found = []
+    for line in hlo.splitlines():
+        op = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) "
+                      r"(copy|copy-start|transpose|fusion)\(", line)
+        if op and any(math.prod(map(int, dims.split(","))) >= pool_elems
+                      for dims in re.findall(r"\[([\d,]+)\]", op[1])):
+            found.append(line.strip()[:160])
+    return found
+
+
+def _compile_paged(sharding, b, hq, hkv, d, m, mbs, case):
+    """Compile the paged kernel for ``b`` slots of ``mbs`` blocks each;
+    return the compiled HLO and the element count of one pool."""
+    nb = 1 + b * mbs
     pool_dt = jnp.int8 if case == "int8" else jnp.bfloat16
-    pool = (nb, BLOCK, TARGET.n_kv_heads, TARGET.head_dim)
-    args = [jax.ShapeDtypeStruct((BATCH, TARGET.n_heads, m, TARGET.head_dim),
-                                 jnp.bfloat16),
+    pool = (nb, BLOCK, hkv, d)
+    args = [jax.ShapeDtypeStruct((b, hq, m, d), jnp.bfloat16),
             jax.ShapeDtypeStruct(pool, pool_dt),
             jax.ShapeDtypeStruct(pool, pool_dt),
-            jax.ShapeDtypeStruct((BATCH, mbs), jnp.int32),
-            jax.ShapeDtypeStruct((BATCH,), jnp.int32)]
+            jax.ShapeDtypeStruct((b, mbs), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32)]
     kw = {}
     if case == "int8":
         scales = jax.ShapeDtypeStruct(pool[:3] + (1,), jnp.float32)
         kw = {"k_scale": scales, "v_scale": scales}
-    anc = (jnp.asarray(tree_spec((3, 2))["anc_bits"]) if tree else None)
+    anc = (jnp.asarray(tree_spec((3, 2))["anc_bits"]) if case == "tree"
+           else None)
     assert anc is None or anc.shape == (m,)
 
     def step(q, kp, vp, bt, lens, **scales):
@@ -77,9 +90,28 @@ def test_paged_kernel_compiles_at_published_widths(one_chip, case):
                                           **scales)
 
     with ops.compiled_kernels(True):
-        compiled = jax.jit(step).lower(*_on(one_chip, args),
-                                       **_on(one_chip, kw)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        compiled = jax.jit(step).lower(*_on(sharding, args),
+                                       **_on(sharding, kw)).compile()
+    return compiled.as_text(), math.prod(pool)
+
+
+@pytest.mark.parametrize("case", ["chain", "tree", "int8"])
+def test_paged_kernel_compiles_at_published_widths(one_chip, case):
+    m = 10 if case == "tree" else N_CAND + 1        # (3, 2) tree: 10 nodes
+    hlo, pool = _compile_paged(one_chip, BATCH, TARGET.n_heads,
+                               TARGET.n_kv_heads, TARGET.head_dim, m,
+                               MAX_LEN // BLOCK, case)
+    assert "tpu_custom_call" in hlo
+    assert not _pool_relayouts(hlo, pool)
+
+
+def test_paged_kernel_reads_pools_in_place_at_8x22b_cell_shapes(one_chip):
+    """The ``mixtral-8x22b.offline`` cell's verify call: 16 slots of 98
+    blocks, Mixtral-8x22B's 48 q / 8 kv heads of 128, m = 5, bf16 pools.
+    The kernel reads the pools as stored: no copy of either."""
+    hlo, pool = _compile_paged(one_chip, 16, 48, 8, 128, 5, 98, "chain")
+    assert "tpu_custom_call" in hlo
+    assert not _pool_relayouts(hlo, pool)
 
 
 def test_fused_step_compiles_and_fits_one_chip(one_chip):

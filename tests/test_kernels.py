@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.decode_attention import pages_per_step
 
 KEY = jax.random.PRNGKey(0)
 
@@ -80,23 +81,78 @@ def _paged_case(key, b, hkv, mbs, bs, d, dtype, quant=False):
     return kp, vp, perm.astype(jnp.int32), scales
 
 
+def _random_lens(*case):
+    """A case whose lengths are drawn at random; its id is its shape."""
+    return pytest.param(*case, None, id="-".join(map(str, case)))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,hq,hkv,m,mbs,bs,d,quant", [
-    (2, 4, 2, 1, 4, 16, 64, False),    # plain paged decode
-    (2, 4, 2, 5, 4, 16, 64, False),    # speculative verify (n_cand=4)
-    (1, 8, 1, 4, 8, 8, 128, False),    # MQA, small blocks
-    (2, 2, 2, 3, 3, 32, 64, True),     # int8 cold blocks + scales
-    (1, 4, 2, 4, 5, 16, 64, True),     # int8, MBS not covering full pool
+@pytest.mark.parametrize("b,hq,hkv,m,mbs,bs,d,quant,lens", [
+    _random_lens(2, 4, 2, 1, 4, 16, 64, False),  # plain paged decode
+    _random_lens(2, 4, 2, 5, 4, 16, 64, False),  # speculative verify (m=5)
+    _random_lens(1, 8, 1, 4, 8, 8, 128, False),  # MQA, small blocks
+    _random_lens(2, 2, 2, 3, 3, 32, 64, True),   # int8 cold blocks + scales
+    _random_lens(1, 4, 2, 4, 5, 16, 64, True),   # int8, MBS < full pool
+    # several page groups, MBS not a multiple of the group: GQA 6 with
+    # m=5 (30 q rows a KV head); lengths ending inside the first group,
+    # on a group boundary, mid-page, and at the table's end
+    pytest.param(4, 48, 8, 5, 10, 64, 128, False, (37, 256, 520, 640),
+                 id="gqa6-m5-groups"),
+    pytest.param(4, 16, 8, 4, 7, 64, 128, True, (21, 320, 350, 448),
+                 id="int8-groups"),
 ])
-def test_paged_decode_attention(dtype, b, hq, hkv, m, mbs, bs, d, quant):
+def test_paged_decode_attention(dtype, b, hq, hkv, m, mbs, bs, d, quant,
+                                lens):
     ks = jax.random.split(KEY, 3)
     q = _rand((b, hq, m, d), dtype, ks[0])
     kp, vp, bt, scales = _paged_case(ks[1], b, hkv, mbs, bs, d, dtype, quant)
-    lengths = jax.random.randint(ks[2], (b,), m + 1,
-                                 mbs * bs + 1).astype(jnp.int32)
+    if lens is None:
+        lengths = jax.random.randint(ks[2], (b,), m + 1,
+                                     mbs * bs + 1).astype(jnp.int32)
+    else:   # the case's lengths must span several page groups
+        pages = pages_per_step(bs, hkv, d, kp.dtype, mbs)
+        assert mbs % pages and max(lens) > pages * bs > min(lens)
+        lengths = jnp.asarray(lens, jnp.int32)
     got = ops.paged_decode_attention(q, kp, vp, bt, lengths,
                                      interpret=True, **scales)
     want = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths, **scales)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **TOL[dtype])
+
+
+@pytest.mark.parametrize("pool", [jnp.float32, jnp.bfloat16, jnp.int8])
+def test_paged_never_reads_past_length(pool):
+    """Every row past a slot's length, in the pages its table names past
+    the length and in the tail of its last page, is NaN: the kernel reads
+    none of them, so it matches the reference run on the finite pool.  A
+    table entry past the length may even be -1."""
+    b, hq, hkv, m, mbs, bs, d = 3, 4, 2, 5, 6, 16, 64
+    quant = pool == jnp.int8
+    dtype = jnp.float32 if quant else pool
+    ks = jax.random.split(KEY, 2)
+    q = _rand((b, hq, m, d), dtype, ks[0])
+    kp, vp, bt, scales = _paged_case(ks[1], b, hkv, mbs, bs, d, dtype, quant)
+    lengths = np.array([21, 48, 5], np.int32)   # mid-page, boundary, 1 page
+    # dead[block, row]: the row holds a logical position >= its slot's length
+    dead = np.zeros(kp.shape[:2], bool)
+    pos = np.arange(mbs * bs).reshape(mbs, bs)
+    for i in range(b):
+        dead[np.asarray(bt[i])] = pos >= lengths[i]
+    poison = jnp.asarray(dead)[:, :, None, None]
+    if quant:   # int8 rows cannot be NaN: poison their scales
+        bad = {k: jnp.where(poison, jnp.nan, s) for k, s in scales.items()}
+        kp_bad, vp_bad = kp, vp
+    else:
+        bad = scales
+        kp_bad = jnp.where(poison, jnp.nan, kp).astype(kp.dtype)
+        vp_bad = jnp.where(poison, jnp.nan, vp).astype(vp.dtype)
+    bt_bad = bt.at[0, -(-int(lengths[0]) // bs):].set(-1)
+    got = ops.paged_decode_attention(q, kp_bad, vp_bad, bt_bad,
+                                     jnp.asarray(lengths), interpret=True,
+                                     **bad)
+    want = ref.paged_decode_attention_ref(q, kp, vp, bt, jnp.asarray(lengths),
+                                          **scales)
+    assert not np.isnan(np.asarray(got, np.float32)).any()
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
 
