@@ -5,6 +5,8 @@ from repro.configs.base import (INPUT_SHAPES, MISTRAL_7B, MIXTRAL_8X7B,
                                 MIXTRAL_8X22B, InputShape, ModelConfig)
 from repro.configs.chameleon_34b import CONFIG as CHAMELEON_34B
 from repro.configs.gemma3_12b import CONFIG as GEMMA3_12B
+from repro.configs.kimi_k2_v5e_pair import DRAFT as MOONLIGHT_5L
+from repro.configs.kimi_k2_v5e_pair import TARGET as KIMI_K2_5L
 from repro.configs.llama3_405b import CONFIG as LLAMA3_405B
 from repro.configs.llama4_maverick_400b import CONFIG as LLAMA4_MAVERICK
 from repro.configs.mixtral_8x7b_v5e_pair import DRAFT as MISTRAL_7B_4L
@@ -37,12 +39,16 @@ PAPER_MODELS = {
     "mistral-7b": MISTRAL_7B,
     "mixtral-8x7b-2l": MIXTRAL_8X7B_2L,
     "mistral-7b-4l": MISTRAL_7B_4L,
+    "kimi-k2-5l": KIMI_K2_5L,
+    "moonlight-16b-a3b-5l": MOONLIGHT_5L,
 }
 
 # Target/draft pairs served as they are, at published widths (no
-# ``.reduced()``); see repro/configs/mixtral_8x7b_v5e_pair.py.
+# ``.reduced()``); see repro/configs/mixtral_8x7b_v5e_pair.py and
+# repro/configs/kimi_k2_v5e_pair.py.
 PAIRS = {
     "mixtral-8x7b-v5e-pair": (MIXTRAL_8X7B_2L, MISTRAL_7B_4L),
+    "kimi-k2-moonlight-v5e-pair": (KIMI_K2_5L, MOONLIGHT_5L),
 }
 
 ALL_CONFIGS = {**ARCHS, **PAPER_MODELS}

@@ -16,8 +16,10 @@ ATTN = "attn"      # global (full, causal) attention
 SWA = "swa"        # sliding-window (local) attention
 RGLRU = "rglru"    # RG-LRU recurrent block (Griffin / RecurrentGemma)
 RWKV = "rwkv"      # RWKV-6 time-mix block (attention-free)
+MLA = "mla"        # multi-head latent attention (DeepSeek-V2/V3 block)
 
-LAYER_KINDS = (ATTN, SWA, RGLRU, RWKV)
+LAYER_KINDS = (ATTN, SWA, RGLRU, RWKV, MLA)
+ROUTERS = ("softmax", "sigmoid_bias")
 
 
 @dataclass(frozen=True)
@@ -50,10 +52,40 @@ class ModelConfig:
     # which layer_pattern positions use the MoE FFN (None -> all, when moe);
     # e.g. llama4-maverick interleaves dense and MoE layers 1:1
     moe_pattern: tuple = ()
+    # expert width (0 -> d_ff); the leading dense layers and non-MoE
+    # pattern positions keep d_ff
+    expert_d_ff: int = 0
+    # experts every token passes through besides its routed ones, held
+    # as one MLP of width n_shared_experts * expert_d_ff
+    n_shared_experts: int = 0
+    # the router's published width (0 -> n_experts).  This model holds
+    # routed experts [expert_offset, expert_offset + n_experts) of them:
+    # one chip's share under expert parallelism
+    router_experts: int = 0
+    expert_offset: int = 0
+    # 'softmax': softmax gates, top-k renormalized (Mixtral);
+    # 'sigmoid_bias': sigmoid scores, top-k chosen on score plus a learned
+    # per-expert correction bias, gates the unbiased scores renormalized
+    # over the chosen (DeepSeek-V3 noaux_tc with one group, norm_topk_prob)
+    router: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # leading layers with a dense FFN of width d_ff, outside the scan over
+    # MoE groups (DeepSeek-V3 first_k_dense_replace)
+    first_dense_layers: int = 0
+    # latent attention (MLA): q_lora_rank 0 projects q from x directly
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rotary for MLA, as the published config's rope_scaling mapping
+    # (kept as sorted (key, value) pairs so the config stays hashable)
+    rope_scaling: tuple = ()
     # positional / misc
     rope_theta: float = 10_000.0
     use_rope: bool = True
     norm: str = "rmsnorm"                # rmsnorm|layernorm
+    norm_eps: float = 1e-6
     activation: str = "swiglu"           # swiglu|gelu|geglu
     tie_embeddings: bool = False
     # encoder-decoder (whisper)
@@ -83,8 +115,19 @@ class ModelConfig:
     source: str = ""                     # citation for the config
 
     def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
         if self.head_dim == 0:
-            object.__setattr__(self, "head_dim", self.d_model // max(self.n_heads, 1))
+            object.__setattr__(
+                self, "head_dim",
+                self.qk_nope_head_dim + self.qk_rope_head_dim
+                if MLA in self.layer_pattern
+                else self.d_model // max(self.n_heads, 1))
+        if self.expert_d_ff == 0:
+            object.__setattr__(self, "expert_d_ff", self.d_ff)
+        if self.router_experts == 0:
+            object.__setattr__(self, "router_experts", self.n_experts)
         if self.rnn_width == 0:
             object.__setattr__(self, "rnn_width", self.d_model)
         if self.n_layers % len(self.layer_pattern) != 0:
@@ -99,15 +142,65 @@ class ModelConfig:
             raise ValueError(f"{self.name}: moe arch needs n_experts/top_k")
         if self.is_moe and not self.moe_pattern:
             object.__setattr__(self, "moe_pattern",
-                               tuple(k in (ATTN, SWA)
+                               tuple(k in (ATTN, SWA, MLA)
                                      for k in self.layer_pattern))
         if self.moe_pattern and len(self.moe_pattern) != len(self.layer_pattern):
             raise ValueError(f"{self.name}: moe_pattern length mismatch")
+        self._check_mla_moe()
+
+    def _check_mla_moe(self):
+        if self.router not in ROUTERS:
+            raise ValueError(f"{self.name}: router {self.router!r} not in "
+                             f"{ROUTERS}")
+        if self.expert_offset + self.n_experts > self.router_experts:
+            raise ValueError(f"{self.name}: experts [{self.expert_offset}, "
+                             f"{self.expert_offset + self.n_experts}) are "
+                             f"not all among the router's "
+                             f"{self.router_experts}")
+        if self.is_moe and self.top_k > self.router_experts:
+            raise ValueError(f"{self.name}: top_k above the router's width")
+        if (self.first_dense_layers % len(self.layer_pattern)
+                or self.first_dense_layers >= max(self.n_layers, 1)):
+            raise ValueError(f"{self.name}: first_dense_layers must be whole "
+                             f"layer groups and leave one to scan")
+        if MLA in self.layer_pattern:
+            if min(self.kv_lora_rank, self.qk_nope_head_dim,
+                   self.qk_rope_head_dim, self.v_head_dim) <= 0:
+                raise ValueError(f"{self.name}: MLA needs kv_lora_rank and "
+                                 f"the nope/rope/v head dims")
+            if self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim:
+                raise ValueError(f"{self.name}: MLA head_dim is the q/k head "
+                                 f"dim, nope + rope")
+            if self.kv_cache_dtype == "int8":
+                raise ValueError(f"{self.name}: int8 KV caches do not "
+                                 f"support latent attention")
 
     # ------------------------------------------------------------------
     @property
     def n_groups(self) -> int:
+        """Layer groups in all; the cache is stacked over these."""
         return self.n_layers // len(self.layer_pattern)
+
+    @property
+    def n_dense_groups(self) -> int:
+        """Leading groups with dense FFNs, applied before the scan."""
+        return self.first_dense_layers // len(self.layer_pattern)
+
+    @property
+    def n_scan_groups(self) -> int:
+        return self.n_groups - self.n_dense_groups
+
+    @property
+    def rope_scaling_dict(self) -> dict:
+        return dict(self.rope_scaling)
+
+    @property
+    def kv_row_elems(self) -> int:
+        """KV values one token stores per layer: K and V of every KV head,
+        or for MLA the latent row, c_kv followed by the rotary key."""
+        if MLA in self.layer_pattern:
+            return self.kv_lora_rank + self.qk_rope_head_dim
+        return 2 * self.n_kv_heads * self.head_dim
 
     @property
     def is_moe(self) -> bool:
@@ -124,12 +217,29 @@ class ModelConfig:
     # -- parameter counting (used by placement / planner / roofline) ----
     def param_count(self) -> int:
         """Total parameters (embedding + layers + head)."""
-        d, f = self.d_model, self.d_ff
+        d = self.d_model
         emb = self.vocab_size * d
         head = 0 if self.tie_embeddings else self.vocab_size * d
+        total = (emb + head
+                 + self.n_dense_groups * self._group_params(False)
+                 + self.n_scan_groups * self._group_params(True))
+        if self.encoder_decoder:
+            hd = self.head_dim
+            enc_layer = (2 * d
+                         + d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
+                         + self.n_heads * hd * d + self._ffn_params())
+            cross = (d + d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
+                     + self.n_heads * hd * d)
+            total += self.n_encoder_layers * enc_layer + self.n_layers * cross
+        return total
+
+    def _group_params(self, moe_allowed: bool) -> int:
+        """Parameters of one layer group; ``moe_allowed`` False for the
+        leading dense groups."""
+        d, f = self.d_model, self.d_ff
         per_layer = 0
         for i, kind in enumerate(self.layer_pattern):
-            moe_here = bool(self.is_moe and self.moe_pattern
+            moe_here = bool(moe_allowed and self.is_moe and self.moe_pattern
                             and self.moe_pattern[i])
             per_layer += 2 * d  # two norms
             if kind in (ATTN, SWA):
@@ -137,6 +247,8 @@ class ModelConfig:
                 per_layer += d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
                 per_layer += self.n_heads * hd * d
                 per_layer += self._ffn_params(moe_here)
+            elif kind == MLA:
+                per_layer += self._mla_params() + self._ffn_params(moe_here)
             elif kind == RGLRU:
                 w = self.rnn_width
                 per_layer += 2 * d * w + w * d      # in (x2 branches) + out
@@ -149,40 +261,52 @@ class ModelConfig:
                 per_layer += d * d                  # channel-mix receptance
                 per_layer += 2 * d * f              # channel mix up/down
                 per_layer += 140 * d                # mus, decay lora, u, ln_x
-        n_group_layers = len(self.layer_pattern)
-        total = emb + head + self.n_groups * per_layer
-        if self.encoder_decoder:
-            hd = self.head_dim
-            enc_layer = (2 * d
-                         + d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
-                         + self.n_heads * hd * d + self._ffn_params())
-            cross = (d + d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
-                     + self.n_heads * hd * d)
-            total += self.n_encoder_layers * enc_layer + self.n_layers * cross
-        del n_group_layers
-        return total
+        return per_layer
+
+    def _mla_params(self) -> int:
+        d, h = self.d_model, self.n_heads
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        if self.q_lora_rank:
+            q = d * self.q_lora_rank + self.q_lora_rank \
+                + self.q_lora_rank * h * qk
+        else:
+            q = d * h * qk
+        kv = (d * (self.kv_lora_rank + self.qk_rope_head_dim)
+              + self.kv_lora_rank
+              + self.kv_lora_rank * h * (self.qk_nope_head_dim
+                                         + self.v_head_dim))
+        return q + kv + h * self.v_head_dim * d
+
+    def _expert_params(self) -> int:
+        gated = self.activation in ("swiglu", "geglu")
+        return (3 if gated else 2) * self.d_model * self.expert_d_ff
 
     def _ffn_params(self, moe: bool | None = None) -> int:
         d, f = self.d_model, self.d_ff
         dense = 3 * d * f if self.activation in ("swiglu", "geglu") else 2 * d * f
         moe = self.is_moe if moe is None else moe
         if moe:
-            return self.n_experts * dense + d * self.n_experts  # + router
+            router = d * self.router_experts
+            if self.router == "sigmoid_bias":
+                router += self.router_experts     # score correction bias
+            return ((self.n_experts + self.n_shared_experts)
+                    * self._expert_params() + router)
         return dense
 
     @property
     def n_moe_layers(self) -> int:
         if not self.is_moe:
             return 0
-        return self.n_groups * sum(bool(b) for b in self.moe_pattern)
+        return self.n_scan_groups * sum(bool(b) for b in self.moe_pattern)
 
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: top_k experts only)."""
+        """Parameters touched per token (MoE: at most top_k of the experts
+        held here, and the shared ones)."""
         if not self.is_moe:
             return self.param_count()
-        d, f = self.d_model, self.d_ff
-        dense_ffn = 3 * d * f if self.activation in ("swiglu", "geglu") else 2 * d * f
-        inactive = self.n_moe_layers * (self.n_experts - self.top_k) * dense_ffn
+        inactive = (self.n_moe_layers
+                    * max(self.n_experts - self.top_k, 0)
+                    * self._expert_params())
         return self.param_count() - inactive
 
     def param_bytes(self, bytes_per_param: int = 2) -> int:
@@ -199,6 +323,12 @@ class ModelConfig:
         n_kv = 1 if self.n_kv_heads == 1 else max(1, min(2, self.n_kv_heads))
         while n_heads % n_kv:
             n_kv -= 1
+        n_e = min(n_experts, self.n_experts) if self.is_moe else 0
+        mla = {}
+        if MLA in pat:
+            mla = dict(q_lora_rank=min(self.q_lora_rank, d_model // 2),
+                       kv_lora_rank=d_model // 4, qk_nope_head_dim=16,
+                       qk_rope_head_dim=8, v_head_dim=16)
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
@@ -206,11 +336,15 @@ class ModelConfig:
             d_model=d_model,
             n_heads=n_heads,
             n_kv_heads=n_kv,
-            head_dim=d_model // n_heads,
+            head_dim=24 if mla else d_model // n_heads,
             d_ff=d_model * 3,
+            expert_d_ff=d_model * (3 if self.expert_d_ff == self.d_ff else 2),
+            router_experts=(n_e if self.router_experts == self.n_experts
+                            else min(self.router_experts, 2 * n_e)),
             vocab_size=vocab,
-            n_experts=min(n_experts, self.n_experts) if self.is_moe else 0,
+            n_experts=n_e,
             top_k=min(self.top_k, 2) if self.is_moe else 0,
+            **mla,
             rnn_width=d_model,
             sliding_window=min(self.sliding_window, 64),
             n_encoder_layers=min(2, self.n_encoder_layers),

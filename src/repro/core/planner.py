@@ -32,7 +32,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import MLA, ModelConfig
 from repro.core.spec_decode import (expected_generated,
                                     expected_generated_tree, tree_layout,
                                     tree_n_nodes)
@@ -80,6 +80,8 @@ def layer_ffn_bytes(cfg: ModelConfig, bytes_per: int = 2) -> float:
 
 
 def layer_attn_bytes(cfg: ModelConfig, bytes_per: int = 2) -> float:
+    if MLA in cfg.layer_pattern:
+        return cfg._mla_params() * bytes_per
     hd = cfg.head_dim
     n = (cfg.d_model * cfg.n_heads * hd + 2 * cfg.d_model * cfg.n_kv_heads * hd
          + cfg.n_heads * hd * cfg.d_model)
@@ -87,7 +89,7 @@ def layer_attn_bytes(cfg: ModelConfig, bytes_per: int = 2) -> float:
 
 
 def kv_bytes_per_token(cfg: ModelConfig, bytes_per: int = 2) -> float:
-    return 2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * bytes_per
+    return cfg.n_layers * cfg.kv_row_elems * bytes_per
 
 
 def stored_kv_bytes_per_seq(cfg: ModelConfig, context: int, *,
@@ -104,7 +106,7 @@ def stored_kv_bytes_per_seq(cfg: ModelConfig, context: int, *,
     """
     tokens = context if block_size is None \
         else -(-context // block_size) * block_size
-    elems = 2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
+    elems = cfg.n_layers * cfg.kv_row_elems
     if quant:
         per_tok = elems + 2 * cfg.n_layers * cfg.n_kv_heads * 4
     else:

@@ -214,7 +214,7 @@ def rollback_draft(cfg: ModelConfig, cache, step_pendings, n_keep):
     pos0 = cache["pos"] - m
     new_layers = list(cache["layers"])
     for li, kind in enumerate(cfg.layer_pattern):
-        if kind == "attn":
+        if kind in ("attn", "mla"):
             continue  # full cache: stale rows beyond pos are invisible
         if kind == "swa":
             for i, pend in enumerate(step_pendings):
@@ -368,7 +368,8 @@ def tree_supported(cfg: ModelConfig) -> bool:
     """Tree speculation needs every layer to see the full prefix (the
     ancestor mask subsets full causal attention): all-ATTN decoder-only
     configs.  SWA rings, recurrent state, and cross-attention carry
-    order-dependent state that a branched buffer cannot share."""
+    order-dependent state that a branched buffer cannot share; latent
+    attention (MLA) has no tree-masked path."""
     return (not cfg.encoder_decoder
             and all(kind == ATTN for kind in cfg.layer_pattern))
 

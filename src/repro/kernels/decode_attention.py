@@ -18,6 +18,14 @@ DMA'd from the pool in HBM in its stored layout, P pages a step
 the next group in flight while one is attended.  Cold blocks may be
 stored int8 with per-row-per-head scales; dequantization happens on the
 VMEM tile after the DMA.
+
+:func:`paged_mla_attention` is the same kernel in latent mode, for
+multi-head latent attention (MLA): one pool of latent rows (one shared
+"KV head" of ``kv_lora_rank + rope`` values a token, padded to whole
+128-lane tiles, as a DMA moves them), each row both key and, in its
+first ``v_dim`` columns, value; every query head attends it (MQA).  At 64 heads x 5 verify rows per 576-value row it is bound by
+compute, not by the pool's bytes, so its products take the inputs' dtype
+(bf16 on the MXU) with float32 accumulation.
 """
 from __future__ import annotations
 
@@ -189,20 +197,24 @@ def _head_rows(ref, h: int, hkv: int) -> jax.Array:
     return ref[pl.ds(h, n, stride=hkv), :].astype(jnp.float32)
 
 
-def _paged_kernel(bt_ref, lens_ref, q_ref, k_hbm, v_hbm, *refs, scale: float,
+def _paged_kernel(bt_ref, lens_ref, q_ref, k_hbm, *refs, scale: float,
                   block_size: int, pages: int, max_blocks: int,
-                  m_tokens: int, quant: bool, tree: bool):
+                  m_tokens: int, quant: bool, tree: bool, latent_v: int = 0):
     """One sequence (grid step): all its KV heads, page group by group.
 
     Page groups stream from the pools in HBM into two VMEM buffers: the
     DMAs of the next group (this sequence's, or the next sequence's
     first) are in flight while this one is attended.  Only the pages
     below ``lengths`` are copied; the group loop ends at the last one.
+    ``latent_v``: latent mode (one pool, no V pool; V is the first
+    ``latent_v`` columns of each row).
     """
     refs = list(refs)
+    v_hbm = None if latent_v else refs.pop(0)
     ks_hbm, vs_hbm = (refs.pop(0), refs.pop(0)) if quant else (None, None)
     anc_ref = refs.pop(0) if tree else None
-    o_ref, kbuf, vbuf = refs.pop(0), refs.pop(0), refs.pop(0)
+    o_ref, kbuf = refs.pop(0), refs.pop(0)
+    vbuf = None if latent_v else refs.pop(0)
     ksbuf, vsbuf, stage = ((refs.pop(0), refs.pop(0), refs.pop(0)) if quant
                            else (None, None, None))
     sems, next_buf, m_scr, l_scr, acc_scr = refs
@@ -218,7 +230,7 @@ def _paged_kernel(bt_ref, lens_ref, q_ref, k_hbm, v_hbm, *refs, scale: float,
         """How many of sequence ``s``'s page group ``g`` are live."""
         return jnp.minimum(pages, live_pages(s) - g * pages)
 
-    pairs = [(k_hbm, kbuf), (v_hbm, vbuf)]
+    pairs = [(k_hbm, kbuf)] if latent_v else [(k_hbm, kbuf), (v_hbm, vbuf)]
     if quant:
         pairs += [(ks_hbm, ksbuf), (vs_hbm, vsbuf)]
 
@@ -268,6 +280,23 @@ def _paged_kernel(bt_ref, lens_ref, q_ref, k_hbm, v_hbm, *refs, scale: float,
         # live page filled) hold anything: their p is 0, and so is their v
         live = (g * n + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
                 < length)
+        if latent_v:
+            # MQA over the latent rows, products in the inputs' dtype
+            k = kbuf.at[buf].reshape(pages * rows, d)[...]       # (n, d)
+            v = jnp.where(live, k[:, :latent_v], 0)
+            s = jax.lax.dot_general(q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(ok, s * scale, NEG_INF)
+            m_prev, l_prev = m_scr[0], l_scr[0]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_scr[0] = l_prev * corr + p.sum(axis=-1, keepdims=True)
+            acc_scr[0] = acc_scr[0] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[0] = m_new
+            return
         if quant:
             # dequantize each live page on its VMEM tile: a page's scales
             # are one lane row, taken as a (rows, 1) column
@@ -414,3 +443,68 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
     return out.reshape(b, hq, m, d)
+
+
+# tokens one latent-mode step attends: bounds its (H*m, tokens) score tile
+LATENT_GROUP_TOKENS = 512
+
+
+def paged_mla_attention(q: jax.Array, pool: jax.Array,
+                        block_tables: jax.Array, lengths: jax.Array, *,
+                        v_dim: int, scale: float | None = None,
+                        interpret: bool = False) -> jax.Array:
+    """Verify-attention of MLA's absorbed form against a paged latent pool.
+
+    q (B, H, m, R) — each head's absorbed query ``[q_nope W_UK ‖ q_pe]``
+    for the m new tokens, already written into the pool at [len-m, len);
+    pool (NB, BS, 1, R) latent rows ``c_kv ‖ k_pe``; block_tables (B, MBS)
+    and lengths (B,) as in :func:`paged_decode_attention`.  Every head
+    attends the one latent "KV head"; the value is a row's first
+    ``v_dim`` columns.  Causal within the m tokens.  Returns the attended
+    latent (B, H, m, v_dim); the caller applies ``W_UV``.
+
+    The kernel is :func:`paged_decode_attention`'s in latent mode: one
+    grid step per sequence, its live pages DMA'd from the pool as stored,
+    P a step (the most whose double buffer fits
+    :data:`PAGE_BUFFER_BYTES`, and no more than
+    :data:`LATENT_GROUP_TOKENS` tokens), against one (H*m, R) query tile.
+    """
+    b, hq, m, d = q.shape
+    nb, bs, one, _ = pool.shape
+    assert one == 1, "a latent pool has one row a token"
+    mbs = block_tables.shape[1]
+    scale = d ** -0.5 if scale is None else scale
+    per_page = 2 * bs * d * jnp.dtype(pool.dtype).itemsize
+    pages = max(1, min(mbs, PAGE_BUFFER_BYTES // per_page,
+                       LATENT_GROUP_TOKENS // bs))
+    qf = q.reshape(b, 1, hq * m, d)
+    kernel = functools.partial(
+        _paged_kernel, scale=scale, block_size=bs, pages=pages,
+        max_blocks=mbs, m_tokens=m, quant=False, tree=False,
+        latent_v=v_dim)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, 1, hq * m, d),
+                                   lambda i, *_: (i, 0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, 1, hq * m, v_dim),
+                                   lambda i, *_: (i, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages, bs, d), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((1, hq * m, 1), jnp.float32),
+                pltpu.VMEM((1, hq * m, 1), jnp.float32),
+                pltpu.VMEM((1, hq * m, v_dim), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, 1, hq * m, v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_mla_attention",
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qf,
+      pool.reshape(nb, bs, d))
+    return out.reshape(b, hq, m, v_dim)

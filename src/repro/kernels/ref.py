@@ -104,6 +104,27 @@ def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths, *,
                                 anc_mask=anc_mask).astype(q.dtype)
 
 
+def paged_mla_attention_ref(q, pool, block_tables, lengths, *, v_dim,
+                            scale=None):
+    """Oracle for the latent-mode kernel: gather each sequence's latent
+    rows (B, S, R), then causal MQA with the rows' first ``v_dim`` columns
+    as values.  q (B, H, m, R) -> (B, H, m, v_dim)."""
+    b, h, m, d = q.shape
+    nb, bs = pool.shape[:2]
+    bt = jnp.maximum(block_tables.astype(jnp.int32), 0)
+    idx = (bt[:, :, None] * bs
+           + jnp.arange(bs, dtype=jnp.int32)[None, None, :]).reshape(b, -1)
+    rows = pool.reshape(nb * bs, d)[idx].astype(jnp.float32)   # (B, S, R)
+    scale = d ** -0.5 if scale is None else scale
+    s = jnp.einsum("bhqd,bkd->bhqk", q.astype(jnp.float32), rows) * scale
+    kp = jnp.arange(rows.shape[1])[None, None, :]
+    qp = lengths[:, None, None] - m + jnp.arange(m)[None, :, None]
+    ok = (kp <= qp) & (kp < lengths[:, None, None])
+    p = jax.nn.softmax(jnp.where(ok[:, None], s, -1e30), axis=-1)
+    out = jnp.einsum("bhqk,bkd->bhqd", p, rows[..., :v_dim])
+    return out.astype(q.dtype)
+
+
 def moe_ffn_ref(buf, w_gate, w_up, w_down, *, activation="swiglu"):
     act = jax.nn.silu if activation == "swiglu" else jax.nn.gelu
     buff = buf.astype(jnp.float32)

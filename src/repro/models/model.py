@@ -89,7 +89,7 @@ def loss_fn(params: dict, cfg: ModelConfig, batch: dict,
     enc_out = _encoder_out(params, cfg, batch)
     h, _, _ = forward_decoder(params, cfg, x, phase="train", mesh=mesh,
                               enc_out=enc_out)
-    h = apply_norm(params["final_norm"], h, cfg.norm)
+    h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
     h = h[:, :-1]
     targets = batch["tokens"][:, 1:].astype(jnp.int32)
 

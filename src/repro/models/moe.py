@@ -20,6 +20,16 @@ Distribution modes (selected per call):
 
 All modes share ``_dispatch``/``_combine``/``_expert_ffn`` so the math is
 identical; ``ep``/``tp`` run inside ``jax.shard_map``.
+
+DeepSeek-V3-style layers (``local`` mode only) add three things:
+``router='sigmoid_bias'`` scores experts with a sigmoid, chooses the top-k
+on score plus a per-expert correction bias, and takes the gates from the
+unbiased scores (renormalized over the chosen, times a scaling factor);
+shared experts (``params['shared']``, one MLP) that every token passes
+through; and an expert *share*: the router keeps its published width
+``router_experts`` while the layer holds and computes only experts
+``[expert_offset, expert_offset + n_experts)``, so a token's result is the
+sum over its chosen experts that are held here (plus the shared experts).
 """
 from __future__ import annotations
 
@@ -27,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.layers import _act, dense_init
+from repro.models.layers import _act, apply_mlp, dense_init, init_mlp
 
 
 # ---------------------------------------------------------------------------
@@ -35,16 +45,27 @@ from repro.models.layers import _act, dense_init
 
 
 def init_moe(key, d_model: int, d_ff: int, n_experts: int, activation: str,
-             dtype) -> dict:
+             dtype, *, router_experts: int | None = None, n_shared: int = 0,
+             score_bias: bool = False) -> dict:
+    """Held experts' weights and the router over ``router_experts`` (the
+    held count by default); ``n_shared`` shared experts of width ``d_ff``
+    as one MLP; ``score_bias`` adds the sigmoid router's correction bias."""
     ks = jax.random.split(key, 4)
     gated = activation in ("swiglu", "geglu")
     p = {
-        "router": dense_init(ks[0], d_model, n_experts, jnp.float32),
+        "router": dense_init(ks[0], d_model, router_experts or n_experts,
+                             jnp.float32),
         "w_up": _expert_init(ks[1], n_experts, d_model, d_ff, dtype),
         "w_down": _expert_init(ks[2], n_experts, d_ff, d_model, dtype),
     }
     if gated:
         p["w_gate"] = _expert_init(ks[3], n_experts, d_model, d_ff, dtype)
+    if score_bias:
+        p["score_bias"] = jnp.zeros((router_experts or n_experts,),
+                                    jnp.float32)
+    if n_shared:
+        p["shared"] = init_mlp(jax.random.fold_in(key, 1), d_model,
+                               n_shared * d_ff, activation, dtype)
     return p
 
 
@@ -88,13 +109,24 @@ def _view_specs(activation: str, mode: str) -> dict:
 # shared routing math (token-local, used identically in every mode)
 
 
-def _route(router_w, x_flat, n_experts: int, top_k: int):
-    """Top-k routing. Returns (expert_idx (N,k), gate (N,k) f32)."""
+def _route(router_w, x_flat, n_experts: int, top_k: int, score_bias=None,
+           scaling: float = 1.0):
+    """Top-k routing. Returns (expert_idx (N,k), gate (N,k) f32).
+
+    Softmax routing without ``score_bias``; with it, sigmoid scores, the
+    top-k of score + bias, gates the chosen unbiased scores over their
+    sum, times ``scaling``."""
     logits = x_flat.astype(jnp.float32) @ router_w
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, idx = jax.lax.top_k(probs, top_k)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
-    return idx, gate
+    if score_bias is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, idx = jax.lax.top_k(probs, top_k)
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        return idx, gate
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + score_bias, top_k)
+    gate = jnp.take_along_axis(scores, idx, axis=-1)
+    gate = gate / (gate.sum(-1, keepdims=True) + 1e-20)
+    return idx, gate * scaling
 
 
 def _capacity(n_tokens: int, top_k: int, n_experts: int, cf: float) -> int:
@@ -105,17 +137,23 @@ def _capacity(n_tokens: int, top_k: int, n_experts: int, cf: float) -> int:
     return max(cap, 1)
 
 
-def _dispatch(x_flat, idx, n_experts: int, capacity: int):
+def _dispatch(x_flat, idx, n_experts: int, capacity: int, held=None):
     """Scatter tokens into per-expert capacity buffers.
 
+    ``held`` (N, k) bool marks the choices whose expert this layer holds
+    (None: all); the others are dropped.
     Returns (buf (E, C, D), slot (N, k) int32 — slot < 0 means dropped).
     """
     n, k = idx.shape
     flat_e = idx.reshape(-1)                               # (N*k,)
     onehot = jax.nn.one_hot(flat_e, n_experts, dtype=jnp.int32)
+    if held is not None:
+        onehot = onehot * held.reshape(-1, 1).astype(jnp.int32)
     pos = jnp.cumsum(onehot, axis=0) * onehot              # 1-based rank
     slot = (pos.sum(-1) - 1).astype(jnp.int32)             # (N*k,)
     keep = slot < capacity
+    if held is not None:
+        keep = keep & held.reshape(-1)
     slot = jnp.where(keep, slot, -1)
     tok = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)
     safe_e = jnp.where(keep, flat_e, 0)
@@ -152,13 +190,23 @@ def _expert_ffn(params, buf, activation: str):
 
 
 def _moe_local(params, x_flat, *, n_experts, top_k, capacity_factor,
-               activation):
+               activation, expert_offset=0, routed_scaling_factor=1.0):
     n = x_flat.shape[0]
     cap = _capacity(n, top_k, n_experts, capacity_factor)
-    idx, gate = _route(params["router"], x_flat, n_experts, top_k)
-    buf, slot = _dispatch(x_flat, idx, n_experts, cap)
+    idx, gate = _route(params["router"], x_flat, n_experts, top_k,
+                       params.get("score_bias"), routed_scaling_factor)
+    held = None
+    if params["router"].shape[-1] != n_experts:
+        # an expert share: route over the router's width, keep the held
+        local = idx - expert_offset
+        held = (local >= 0) & (local < n_experts)
+        idx = jnp.where(held, local, 0)
+    buf, slot = _dispatch(x_flat, idx, n_experts, cap, held)
     y = _expert_ffn(params, buf, activation)
-    return _combine(y, idx, slot, gate)
+    out = _combine(y, idx, slot, gate)
+    if "shared" in params:
+        out = out + apply_mlp(params["shared"], x_flat, activation)
+    return out
 
 
 def _moe_ep_body(params, x_flat, *, n_experts, top_k, capacity_factor,
@@ -257,15 +305,23 @@ def select_moe_mode(n_experts: int, seq_len: int, mesh) -> str:
 
 def apply_moe(params: dict, x: jax.Array, *, n_experts: int, top_k: int,
               activation: str, mesh=None, capacity_factor: float = 2.0,
-              batch_axis="data", pod_axis=None) -> jax.Array:
-    """MoE FFN over x (B, S, D)."""
+              batch_axis="data", pod_axis=None, **routing) -> jax.Array:
+    """MoE FFN over x (B, S, D).  ``routing`` (``expert_offset``,
+    ``routed_scaling_factor``) configures the DeepSeek-V3-style layer,
+    which runs in ``local`` mode only."""
     b, s, d = x.shape
     mode = select_moe_mode(n_experts, s, mesh)
     kw = dict(n_experts=n_experts, top_k=top_k,
               capacity_factor=capacity_factor, activation=activation)
 
     if mode == "local":
-        return _moe_local(params, x.reshape(-1, d), **kw).reshape(b, s, d)
+        return _moe_local(params, x.reshape(-1, d), **kw,
+                          **routing).reshape(b, s, d)
+    if (routing or "shared" in params or "score_bias" in params
+            or params["router"].shape[-1] != n_experts):
+        raise NotImplementedError(
+            f"MoE mode {mode!r} has no shared experts, sigmoid routing or "
+            f"expert share; those layers run on one device per share")
 
     body = {"ep": _moe_ep_body, "ep_psum": _moe_ep_psum_body,
             "tp": _moe_tp_body}[mode]

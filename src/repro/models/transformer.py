@@ -22,8 +22,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ATTN, RGLRU, RWKV, SWA, ModelConfig
+from repro.configs.base import ATTN, MLA, RGLRU, RWKV, SWA, ModelConfig
 from repro.models import attention as attn_lib
+from repro.models import mla as mla_lib
 from repro.models import moe as moe_lib
 from repro.models import rglru as rglru_lib
 from repro.models import rwkv as rwkv_lib
@@ -57,7 +58,40 @@ def _dtype(cfg: ModelConfig):
 
 
 def _norm_fn(cfg: ModelConfig):
-    return lambda p, x: apply_norm(p, x, cfg.norm)
+    return lambda p, x: apply_norm(p, x, cfg.norm, cfg.norm_eps)
+
+
+def _init_ffn(key, cfg: ModelConfig, use_moe: bool, dt) -> dict:
+    if use_moe:
+        return moe_lib.init_moe(key, cfg.d_model, cfg.expert_d_ff,
+                                cfg.n_experts, cfg.activation, dt,
+                                router_experts=cfg.router_experts,
+                                n_shared=cfg.n_shared_experts,
+                                score_bias=cfg.router == "sigmoid_bias")
+    return init_mlp(key, cfg.d_model, cfg.d_ff, cfg.activation, dt)
+
+
+def _moe_routing(cfg: ModelConfig) -> dict:
+    """apply_moe's DeepSeek-V3-style routing arguments ({} for Mixtral's)."""
+    if (cfg.router == "softmax" and not cfg.n_shared_experts
+            and cfg.router_experts == cfg.n_experts):
+        return {}
+    return {"expert_offset": cfg.expert_offset,
+            "routed_scaling_factor": cfg.routed_scaling_factor}
+
+
+def _apply_ffn(params: dict, cfg: ModelConfig, h: jax.Array, phase: str,
+               mesh, use_moe: bool) -> jax.Array:
+    if use_moe:
+        # decode steps are few-token: dropless dispatch is free there and
+        # keeps speculative verification exact (no batch-dependent drops)
+        cf = (float("inf") if (cfg.moe_dropless or phase == "decode")
+              else cfg.capacity_factor)
+        return moe_lib.apply_moe(params, h, n_experts=cfg.n_experts,
+                                 top_k=cfg.top_k, activation=cfg.activation,
+                                 mesh=mesh, capacity_factor=cf,
+                                 **_moe_routing(cfg))
+    return apply_mlp(params, h, cfg.activation)
 
 
 # ---------------------------------------------------------------------------
@@ -78,12 +112,10 @@ def init_layer(key, cfg: ModelConfig, kind: str,
             p["xattn"] = init_attention(kx[0], cfg.d_model, cfg.n_heads,
                                         cfg.n_kv_heads, cfg.head_dim, dt)
             p["ln_x"] = init_norm(cfg.d_model, cfg.norm, dt)
-        if use_moe:
-            p["ffn"] = moe_lib.init_moe(ks[1], cfg.d_model, cfg.d_ff,
-                                        cfg.n_experts, cfg.activation, dt)
-        else:
-            p["ffn"] = init_mlp(ks[1], cfg.d_model, cfg.d_ff,
-                                cfg.activation, dt)
+        p["ffn"] = _init_ffn(ks[1], cfg, use_moe, dt)
+    elif kind == MLA:
+        p["attn"] = mla_lib.init_mla(ks[0], cfg, dt)
+        p["ffn"] = _init_ffn(ks[1], cfg, use_moe, dt)
     elif kind == RGLRU:
         p["rec"] = rglru_lib.init_rglru(ks[0], cfg.d_model, cfg.rnn_width,
                                         cfg.conv_width, dt)
@@ -97,6 +129,17 @@ def init_layer(key, cfg: ModelConfig, kind: str,
     return p
 
 
+def _ffn_specs(cfg: ModelConfig, model_size: int, use_moe: bool) -> dict:
+    if not use_moe:
+        return mlp_specs(cfg.activation)
+    p = moe_lib.moe_storage_specs(cfg.activation, cfg.n_experts, model_size)
+    if cfg.router == "sigmoid_bias":
+        p["score_bias"] = P(None)
+    if cfg.n_shared_experts:
+        p["shared"] = mlp_specs(cfg.activation)
+    return p
+
+
 def layer_specs(cfg: ModelConfig, kind: str, model_size: int,
                 use_moe: bool = False) -> dict:
     p = {"ln1": norm_specs(cfg.norm), "ln2": norm_specs(cfg.norm)}
@@ -105,11 +148,10 @@ def layer_specs(cfg: ModelConfig, kind: str, model_size: int,
         if cfg.encoder_decoder:
             p["xattn"] = attn_lib.attention_specs()
             p["ln_x"] = norm_specs(cfg.norm)
-        if use_moe:
-            p["ffn"] = moe_lib.moe_storage_specs(cfg.activation,
-                                                 cfg.n_experts, model_size)
-        else:
-            p["ffn"] = mlp_specs(cfg.activation)
+        p["ffn"] = _ffn_specs(cfg, model_size, use_moe)
+    elif kind == MLA:
+        p["attn"] = mla_lib.mla_specs(cfg)
+        p["ffn"] = _ffn_specs(cfg, model_size, use_moe)
     elif kind == RGLRU:
         p["rec"] = rglru_lib.rglru_specs()
         p["ffn"] = mlp_specs(cfg.activation)
@@ -182,19 +224,21 @@ def apply_layer(params: dict, cfg: ModelConfig, kind: str, x: jax.Array,
             if new_kv is not None:
                 new_kv = dict(new_kv, **cross_kv)
         h = nf(params["ln2"], x)
-        if use_moe:
-            # decode steps are few-token: dropless dispatch is free there and
-            # keeps speculative verification exact (no batch-dependent drops)
-            cf = (float("inf") if (cfg.moe_dropless or phase == "decode")
-                  else cfg.capacity_factor)
-            f = moe_lib.apply_moe(params["ffn"], h, n_experts=cfg.n_experts,
-                                  top_k=cfg.top_k, activation=cfg.activation,
-                                  mesh=mesh, capacity_factor=cf)
-        else:
-            f = apply_mlp(params["ffn"], h, cfg.activation)
-        x = x + f
+        x = x + _apply_ffn(params["ffn"], cfg, h, phase, mesh, use_moe)
         if phase == "decode":
             pending = {"saved": saved}
+        return x, new_kv, pending
+
+    if kind == MLA:
+        if spec_tree is not None and phase == "decode":
+            raise ValueError("tree speculation does not support latent "
+                             "attention (MLA)")
+        out, new_kv = mla_lib.apply_mla(
+            params["attn"], nf(params["ln1"], x), cfg, cache=cache, pos=pos,
+            phase=phase, block_tables=block_tables)
+        x = x + out
+        h = nf(params["ln2"], x)
+        x = x + _apply_ffn(params["ffn"], cfg, h, phase, mesh, use_moe)
         return x, new_kv, pending
 
     if kind == RGLRU:
@@ -241,6 +285,8 @@ def init_layer_cache(cfg: ModelConfig, kind: str, batch: int,
     if kind == SWA:
         return init_kv_cache(batch, min(cfg.sliding_window, max_len),
                              cfg.n_kv_heads, cfg.head_dim, dt)
+    if kind == MLA:
+        return mla_lib.init_mla_cache(batch, max_len, cfg, dt)
     if kind == RGLRU:
         return rglru_lib.init_rglru_state(batch, cfg.rnn_width,
                                           cfg.conv_width, dt)
@@ -271,7 +317,8 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_blocks: int,
     """Serving cache with *paged* full-attention KV.
 
     ATTN layers share one ``(num_blocks, block_size, ...)`` pool per layer
-    group; each sequence addresses it through its ``block_tables`` row
+    group (MLA layers one latent pool, ``(num_blocks, block_size, 1,
+    R)``); each sequence addresses it through its ``block_tables`` row
     (``max_blocks_per_seq`` entries, 0 = the reserved scratch block).
     Sliding-window / recurrent layers keep their per-slot state — rings
     are window-bounded, so paging them buys nothing.  ``kv_quant``
@@ -281,12 +328,18 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_blocks: int,
     if cfg.encoder_decoder:
         raise ValueError("paged KV serving supports decoder-only models")
     quant = (cfg.kv_cache_dtype == "int8") if kv_quant is None else kv_quant
+    if quant and MLA in cfg.layer_pattern:
+        raise ValueError("int8 KV pools do not support latent attention "
+                         "(MLA): its pool holds one latent row a token")
     dt = _dtype(cfg)
     layers = []
     for kind in cfg.layer_pattern:
         if kind == ATTN:
             one = init_paged_kv_pool(num_blocks, block_size, cfg.n_kv_heads,
                                      cfg.head_dim, dt, quant=quant)
+        elif kind == MLA:
+            one = mla_lib.init_paged_mla_pool(num_blocks, block_size, cfg,
+                                              dt)
         else:
             one = init_layer_cache(cfg, kind, batch,
                                    max_blocks_per_seq * block_size)
@@ -319,7 +372,7 @@ def admit_sequence_paged(cfg: ModelConfig, cache: dict, prefill: dict,
     new_layers = []
     for i, kind in enumerate(cfg.layer_pattern):
         big, small = cache["layers"][i], prefill["layers"][i]
-        if kind == ATTN:
+        if kind in (ATTN, MLA):
             new_layers.append(_paged_insert_layer(big, small, row, start,
                                                   length))
         else:
@@ -334,8 +387,8 @@ def admit_sequence_paged(cfg: ModelConfig, cache: dict, prefill: dict,
 
 def _paged_block_size(cache: dict, cfg: ModelConfig) -> int:
     for i, kind in enumerate(cfg.layer_pattern):
-        if kind == ATTN:
-            return cache["layers"][i]["k"].shape[2]
+        if kind in (ATTN, MLA):
+            return next(iter(cache["layers"][i].values())).shape[2]
     raise ValueError("paged cache has no full-attention layer")
 
 
@@ -347,8 +400,8 @@ def _paged_insert_layer(pool: dict, prefill: dict, table_row, start,
     d).  Rows outside [start, length) are redirected to the scratch block
     (block 0), which the engine never grants.
     """
-    g, nb, bs = pool["k"].shape[:3]
-    l = prefill["k"].shape[2]
+    g, nb, bs = next(iter(pool.values())).shape[:3]
+    l = next(iter(prefill.values())).shape[2]
     i = jnp.arange(l, dtype=jnp.int32)
     valid = (i >= start) & (i < length)
     idx = paged_row_indices(jnp.asarray(table_row)[None, :], i[None, :],
@@ -402,6 +455,8 @@ def cache_specs(cfg: ModelConfig, batch_spec, seq_spec) -> dict:
             if cfg.encoder_decoder and kind == ATTN:
                 one["ck"] = P(batch_spec, None, None, None)
                 one["cv"] = P(batch_spec, None, None, None)
+        elif kind == MLA:
+            one = {"latent": P(batch_spec, seq_spec, None, None)}
         elif kind == RGLRU:
             one = rglru_lib.rglru_state_specs(batch_spec)
         else:
@@ -416,37 +471,57 @@ def cache_specs(cfg: ModelConfig, batch_spec, seq_spec) -> dict:
 # stacked init / specs for the whole decoder
 
 
-def init_decoder_params(key, cfg: ModelConfig) -> dict:
-    keys = jax.random.split(key, len(cfg.layer_pattern) + 2)
+def _init_groups(keys, cfg: ModelConfig, n: int, moe_allowed: bool) -> tuple:
+    """Params of ``n`` layer groups, each pattern position stacked
+    (``keys``: one per pattern position)."""
     layers = []
     for i, kind in enumerate(cfg.layer_pattern):
-        gkeys = jax.random.split(keys[i], cfg.n_groups)
-        moe_i = bool(cfg.is_moe and cfg.moe_pattern[i])
+        gkeys = jax.random.split(keys[i], n)
+        moe_i = bool(moe_allowed and cfg.is_moe and cfg.moe_pattern[i])
         layers.append(jax.vmap(
             lambda k, m=moe_i: init_layer(k, cfg, kind, m))(gkeys))
+    return tuple(layers)
+
+
+def init_decoder_params(key, cfg: ModelConfig) -> dict:
+    """``layers`` holds the scanned groups; ``dense_layers`` (only when
+    ``cfg.first_dense_layers``) the leading dense groups before them."""
+    n_pat = len(cfg.layer_pattern)
+    keys = jax.random.split(key, n_pat + 2)
     dt = _dtype(cfg)
-    return {
+    params = {
         "embed": init_embedding(keys[-2], cfg.vocab_size, cfg.d_model, dt,
                                 cfg.tie_embeddings),
-        "layers": tuple(layers),
+        "layers": _init_groups(keys[:n_pat], cfg, cfg.n_scan_groups, True),
         "final_norm": init_norm(cfg.d_model, cfg.norm, dt),
     }
+    if cfg.n_dense_groups:
+        params["dense_layers"] = _init_groups(
+            jax.random.split(keys[-1], n_pat), cfg, cfg.n_dense_groups,
+            False)
+    return params
 
 
 def decoder_param_specs(cfg: ModelConfig, model_size: int = 16) -> dict:
-    layers = []
-    for i, kind in enumerate(cfg.layer_pattern):
-        moe_i = bool(cfg.is_moe and cfg.moe_pattern[i])
-        one = layer_specs(cfg, kind, model_size, moe_i)
-        layers.append(jax.tree.map(
-            lambda s: P(None, *s), one,
-            is_leaf=lambda s: isinstance(s, P)))
-    return {
+    def groups(moe_allowed):
+        layers = []
+        for i, kind in enumerate(cfg.layer_pattern):
+            moe_i = bool(moe_allowed and cfg.is_moe and cfg.moe_pattern[i])
+            one = layer_specs(cfg, kind, model_size, moe_i)
+            layers.append(jax.tree.map(
+                lambda s: P(None, *s), one,
+                is_leaf=lambda s: isinstance(s, P)))
+        return tuple(layers)
+
+    specs = {
         "embed": embedding_specs(cfg.tie_embeddings, cfg.vocab_size,
                                  cfg.d_model, model_size),
-        "layers": tuple(layers),
+        "layers": groups(True),
         "final_norm": norm_specs(cfg.norm),
     }
+    if cfg.n_dense_groups:
+        specs["dense_layers"] = groups(False)
+    return specs
 
 
 # ---------------------------------------------------------------------------
@@ -475,10 +550,10 @@ def forward_decoder(params: dict, cfg: ModelConfig, x: jax.Array, *,
 
     train = phase == "train"
 
-    def apply_group(x, gparams, gcache):
+    def apply_group(x, gparams, gcache, moe_allowed=True):
         new_caches, pendings = [], []
         for i, kind in enumerate(cfg.layer_pattern):
-            moe_i = bool(cfg.is_moe and cfg.moe_pattern[i])
+            moe_i = bool(moe_allowed and cfg.is_moe and cfg.moe_pattern[i])
             x, nc, pend = apply_layer(gparams[i], cfg, kind, x, gcache[i],
                                       pos, phase, mesh, enc_out=enc_out,
                                       use_moe=moe_i,
@@ -487,6 +562,23 @@ def forward_decoder(params: dict, cfg: ModelConfig, x: jax.Array, *,
             new_caches.append(nc)
             pendings.append(pend)
         return x, tuple(new_caches), tuple(pendings)
+
+    # leading dense groups (first_dense_layers): applied one by one before
+    # the scan; their caches are the first entries of the stacked cache
+    dense_pendings = []
+    for j in range(cfg.n_dense_groups):
+        gparams = jax.tree.map(lambda a, j=j: a[j], params["dense_layers"])
+        if layer_caches is None:
+            x, _, _ = apply_group(x, gparams, (None,) * len(cfg.layer_pattern),
+                                  moe_allowed=False)
+            continue
+        gcache = jax.tree.map(lambda a, j=j: a[j], layer_caches)
+        x, new_caches, pend = apply_group(x, gparams, gcache,
+                                          moe_allowed=False)
+        layer_caches = jax.tree.map(
+            lambda a, n, j=j: jax.lax.dynamic_update_index_in_dim(
+                a, n.astype(a.dtype), j, 0), layer_caches, new_caches)
+        dense_pendings.append(pend)
 
     if layer_caches is not None:
         # Serving: thread the (stacked) cache through the scan *carry* and
@@ -507,9 +599,13 @@ def forward_decoder(params: dict, cfg: ModelConfig, x: jax.Array, *,
                 cache_layers, new_caches)
             return (x, cache_layers), tuple(pendings)
 
-        idx = jnp.arange(cfg.n_groups, dtype=jnp.int32)
+        idx = jnp.arange(cfg.n_dense_groups, cfg.n_groups, dtype=jnp.int32)
         (x, new_layer_caches), pendings = jax.lax.scan(
             body, (x, layer_caches), (idx, params["layers"]))
+        if dense_pendings:
+            pendings = jax.tree.map(
+                lambda *a: jnp.concatenate([jnp.stack(a[:-1]), a[-1]]),
+                *dense_pendings, pendings)
         new_cache = {"layers": new_layer_caches, "pos": cache["pos"]}
         if block_tables is not None:
             new_cache["block_tables"] = block_tables
@@ -542,13 +638,13 @@ def forward_decoder(params: dict, cfg: ModelConfig, x: jax.Array, *,
             body = jax.checkpoint(body)
 
     n_outer = 1 if (cfg.offload_carries and cfg.remat and train) else (
-        _sqrt_factor(cfg.n_groups) if (cfg.remat and train) else 1)
+        _sqrt_factor(cfg.n_scan_groups) if (cfg.remat and train) else 1)
     if n_outer > 1:
         # sqrt-remat: scan superblocks of groups with an outer checkpoint,
         # so only n_outer + n_inner carries are live instead of n_groups
         # (126-layer models would otherwise store one (B,S,D) residual per
         # layer group).
-        n_inner = cfg.n_groups // n_outer
+        n_inner = cfg.n_scan_groups // n_outer
         xs2 = jax.tree.map(
             lambda a: a.reshape(n_outer, n_inner, *a.shape[1:]),
             params["layers"])
@@ -565,7 +661,7 @@ def forward_decoder(params: dict, cfg: ModelConfig, x: jax.Array, *,
 
 def logits_from_hidden(params: dict, cfg: ModelConfig,
                        x: jax.Array) -> jax.Array:
-    h = apply_norm(params["final_norm"], x, cfg.norm)
+    h = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     return unembed(params["embed"], h)
 
 
@@ -587,7 +683,7 @@ def commit_cache(cfg: ModelConfig, cache: dict, pendings, n_commit,
     for i, kind in enumerate(cfg.layer_pattern):
         c = cache["layers"][i]
         pend = pendings[i]
-        if kind == ATTN:
+        if kind in (ATTN, MLA):
             new_layers.append(c)  # over-written rows are invisible
         elif kind == SWA:
             saved = pend["saved"]

@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ATTN, ModelConfig
+from repro.configs.base import ATTN, MLA, ModelConfig
 from repro.core.interleave import BatchState
 from repro.core.offload import record_transfer
 from repro.core.pipeline import SpecOffloadEngine, required_cache_len
@@ -1169,6 +1169,15 @@ class ServingEngine:
         reg.gauge("serve_replans_total",
                   "online ParaSpec replans triggered").set(
                       len(self.replan_events))
+        # the expert cut: experts held here against the router's width
+        held = reg.gauge("moe_experts_held",
+                         "routed experts held per MoE layer, per model")
+        width = reg.gauge("moe_router_experts",
+                          "experts the router chooses among, per model")
+        for role, cfg in (("target", self.target_cfg),
+                          ("draft", self.draft_cfg)):
+            held.set(cfg.n_experts, model=role)
+            width.set(cfg.router_experts, model=role)
 
     def metrics(self) -> dict:
         """Structured observability snapshot: ``{"metrics": <registry
@@ -1275,7 +1284,7 @@ class ServingEngine:
         """Bytes of the full-attention KV leaves of a target cache."""
         total = 0
         for i, kind in enumerate(self.target_cfg.layer_pattern):
-            if kind == ATTN:
+            if kind in (ATTN, MLA):
                 total += sum(int(leaf.nbytes)
                              for leaf in jax.tree.leaves(cache["layers"][i]))
         return total

@@ -1,8 +1,8 @@
 """Compile the served path for a described (not attached) TPU v5e.
 
 Nothing runs: these tests lower and compile at the published widths of
-the Mixtral-8x7B / Mistral-7B smoke pair (``configs.PAIRS``) for one chip
-of a ``v5e:2x2`` topology, so a kernel tiling or a program size the chip
+the Mixtral-8x7B / Mistral-7B smoke pair and of the Kimi-K2 / Moonlight
+pair (``configs.PAIRS``) for one chip of a ``v5e:2x2`` topology, so a kernel tiling or a program size the chip
 refuses fails here, with no chip.  The topology is described inside a
 module fixture (only one process may load the TPU compiler), and the
 tests skip where it cannot be described.
@@ -26,6 +26,9 @@ from repro.models import model as M
 from repro.models.transformer import init_cache, init_paged_cache
 
 TARGET, DRAFT = PAIRS["mixtral-8x7b-v5e-pair"]
+K2, MOONLIGHT = PAIRS["kimi-k2-moonlight-v5e-pair"]
+# the kimi-k2.offline cell: 16 slots a half, a 1568-token cache (98 blocks)
+K2_BATCH, K2_MBS = 16, 98
 BATCH, N_CAND, MAX_LEN, BLOCK = 8, 4, 2048, 16
 HBM_BYTES = 16e9
 
@@ -114,32 +117,74 @@ def test_paged_kernel_reads_pools_in_place_at_8x22b_cell_shapes(one_chip):
     assert not _pool_relayouts(hlo, pool)
 
 
-def test_fused_step_compiles_and_fits_one_chip(one_chip):
-    """The whole fused verify+draft step of the smoke pair, from
-    ``jax.eval_shape`` shapes, with the routing steered to the kernel."""
+def _compile_fused(sharding, target, draft, batch, mbs):
+    """The whole fused verify+draft step of a pair, from
+    ``jax.eval_shape`` shapes, with the routing steered to the kernel;
+    returns the compiled step and the weights' bytes."""
     key = jax.random.PRNGKey(0)
-    tp = jax.eval_shape(partial(M.init_params, TARGET), key)
-    dp = jax.eval_shape(partial(M.init_params, DRAFT), key)
-    mbs = MAX_LEN // BLOCK
+    tp = jax.eval_shape(partial(M.init_params, target), key)
+    dp = jax.eval_shape(partial(M.init_params, draft), key)
     tcache = jax.eval_shape(lambda: init_paged_cache(
-        TARGET, BATCH, 1 + BATCH * mbs, BLOCK, mbs))
-    dcache = jax.eval_shape(lambda: init_cache(DRAFT, BATCH, MAX_LEN))
-    tok = jax.ShapeDtypeStruct((BATCH,), jnp.int32)
+        target, batch, 1 + batch * mbs, BLOCK, mbs))
+    dcache = jax.eval_shape(lambda: init_cache(draft, batch, mbs * BLOCK))
+    tok = jax.ShapeDtypeStruct((batch,), jnp.int32)
     vstate = {"target_cache": tcache, "t_next": tok,
-              "drafts": jax.ShapeDtypeStruct((BATCH, N_CAND), jnp.int32)}
+              "drafts": jax.ShapeDtypeStruct((batch, N_CAND), jnp.int32)}
     dstate = {"draft_cache": dcache, "t_next": tok}
     fused = jax.jit(fused_verify_and_draft,
                     static_argnames=("target_cfg", "draft_cfg", "n_cand",
                                      "mesh"))
     with ops.compiled_kernels(True):
         compiled = fused.lower(
-            _on(one_chip, tp), TARGET, _on(one_chip, dp), DRAFT,
-            _on(one_chip, vstate), _on(one_chip, dstate), N_CAND,
+            _on(sharding, tp), target, _on(sharding, dp), draft,
+            _on(sharding, vstate), _on(sharding, dstate), N_CAND,
             None).compile()
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree.leaves((tp, dp)))
+    return compiled, weights
+
+
+def test_fused_step_compiles_and_fits_one_chip(one_chip):
+    """The smoke pair's fused step compiles and fits one chip."""
+    compiled, weights = _compile_fused(one_chip, TARGET, DRAFT, BATCH,
+                                       MAX_LEN // BLOCK)
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
-    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                  for a in jax.tree.leaves((tp, dp)))
     assert weights < total < HBM_BYTES, (weights, total)
+
+
+def test_latent_kernel_compiles_at_kimi_k2_cell_shapes(one_chip):
+    """The ``kimi-k2.offline`` cell's verify call in latent mode: 16
+    slots of 98 blocks, 64 heads x 5 rows against one 640-wide latent
+    row a token (576 values and lane padding).  It reads the pool as
+    stored: no copy of it."""
+    rows = 640
+    nb = 1 + K2_BATCH * K2_MBS
+    args = [jax.ShapeDtypeStruct((K2_BATCH, 64, N_CAND + 1, rows),
+                                 jnp.bfloat16),
+            jax.ShapeDtypeStruct((nb, BLOCK, 1, rows), jnp.bfloat16),
+            jax.ShapeDtypeStruct((K2_BATCH, K2_MBS), jnp.int32),
+            jax.ShapeDtypeStruct((K2_BATCH,), jnp.int32)]
+
+    def step(q, pool, bt, lens):
+        return ops.paged_mla_attention(q, pool, bt, lens, v_dim=512)
+
+    with ops.compiled_kernels(True):
+        hlo = jax.jit(step).lower(*_on(one_chip, args)).compile().as_text()
+    assert "tpu_custom_call" in hlo and "%paged_mla_attention" in hlo
+    assert not _pool_relayouts(hlo, nb * BLOCK * rows)
+
+
+def test_kimi_pair_fused_step_compiles_and_fits_one_chip(one_chip):
+    """The Kimi-K2 / Moonlight fused step at the cell's shapes: the
+    latent kernel is in it, and weights, caches and temporaries fit
+    16 GB with room for the second half's caches."""
+    compiled, weights = _compile_fused(one_chip, K2, MOONLIGHT, K2_BATCH,
+                                       K2_MBS)
+    assert "%paged_mla_attention" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert weights < total < 0.9 * HBM_BYTES, (weights, total)
