@@ -2,7 +2,7 @@
 
 flash_attention  — prefill/train attention (the GPU-bound prefill phase)
 decode_attention — skinny-q verification attention against long KV caches
-moe_ffn          — grouped expert SwiGLU (the paper's streamed MoE unit)
+moe_ffn          — routed expert FFN: reads only the experts a decode step chose
 rglru_scan       — RecurrentGemma RG-LRU time scan
 wkv6             — RWKV-6 WKV recurrence
 

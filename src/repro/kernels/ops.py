@@ -65,6 +65,6 @@ flash_attention = _wrap(_fa.flash_attention, "scale", "causal", "window",
 decode_attention = _wrap(_da.decode_attention, "scale", "window", "block_k")
 paged_decode_attention = _wrap(_da.paged_decode_attention, "scale")
 paged_mla_attention = _wrap(_da.paged_mla_attention, "scale", "v_dim")
-moe_ffn = _wrap(_mf.moe_ffn, "activation", "block_c", "block_f")
+routed_expert_ffn = _wrap(_mf.routed_expert_ffn, "activation")
 rglru_scan = _wrap(_rg.rglru_scan, "block_w")
 wkv6 = _wrap(_wk.wkv6)

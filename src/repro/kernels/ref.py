@@ -125,13 +125,26 @@ def paged_mla_attention_ref(q, pool, block_tables, lengths, *, v_dim,
     return out.astype(q.dtype)
 
 
-def moe_ffn_ref(buf, w_gate, w_up, w_down, *, activation="swiglu"):
+def routed_expert_ffn_ref(x, idx, gate, w_gate, w_up, w_down, *,
+                          activation="swiglu"):
+    """The plain per-token sum over chosen experts, in float32: token n
+    adds ``gate[n, j] * FFN_{idx[n, j]}(x[n])`` for each of its k
+    choices.  x (N, D); idx, gate (N, k); w_gate (None: ungated) /
+    w_up (E, D, F), w_down (E, F, D) -> (N, D) f32."""
     act = jax.nn.silu if activation == "swiglu" else jax.nn.gelu
-    buff = buf.astype(jnp.float32)
-    h = act(jnp.einsum("ecd,edf->ecf", buff, w_gate.astype(jnp.float32)))
-    h = h * jnp.einsum("ecd,edf->ecf", buff, w_up.astype(jnp.float32))
-    out = jnp.einsum("ecf,efd->ecd", h, w_down.astype(jnp.float32))
-    return out.astype(buf.dtype)
+    xf = x.astype(jnp.float32)
+    out = jnp.zeros(xf.shape, jnp.float32)
+    for j in range(idx.shape[1]):
+        e = idx[:, j]
+        up = jnp.einsum("nd,ndf->nf", xf, w_up[e].astype(jnp.float32))
+        if w_gate is None:
+            h = act(up)
+        else:
+            h = act(jnp.einsum("nd,ndf->nf", xf,
+                               w_gate[e].astype(jnp.float32))) * up
+        y = jnp.einsum("nf,nfd->nd", h, w_down[e].astype(jnp.float32))
+        out = out + gate[:, j:j + 1].astype(jnp.float32) * y
+    return out
 
 
 def rglru_scan_ref(a, gated, h0):

@@ -30,6 +30,10 @@ through; and an expert *share*: the router keeps its published width
 ``router_experts`` while the layer holds and computes only experts
 ``[expert_offset, expert_offset + n_experts)``, so a token's result is the
 sum over its chosen experts that are held here (plus the shared experts).
+
+A serving layer scan whose calls have few tokens takes the routed path
+(:func:`routed_ffn`): the expert FFN runs in ``kernels/moe_ffn.py`` over
+only the experts some token chose, and reads no weight of the others.
 """
 from __future__ import annotations
 
@@ -37,6 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import ops as kernel_ops
 from repro.models.layers import _act, apply_mlp, dense_init, init_mlp
 
 
@@ -185,6 +190,39 @@ def _expert_ffn(params, buf, activation: str):
     return jnp.einsum("ecf,efd->ecd", h, params["w_down"])
 
 
+# the expert weights the routed FFN reads (w_gate only where gated)
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def routed_ffn(n_tokens: int, n_experts: int, top_k: int,
+               router_experts: int, dropless: bool = True) -> bool:
+    """Whether a ``local`` call of ``n_tokens`` takes the routed expert FFN.
+
+    Only where the layer holds all of its router's experts (an expert
+    share's held experts serve other chips' tokens too, so none may be
+    skipped), drops nothing, and at least one expert is expected to go
+    unread under uniform routing: ``E (1 - k/E)^N >= 1``."""
+    return (router_experts == n_experts and dropless
+            and n_experts * (1 - top_k / n_experts) ** n_tokens >= 1)
+
+
+def _routed_expert_ffn(params, x_flat, idx, gate, n_experts: int,
+                       activation: str):
+    """The chosen experts' FFN, gated and summed per token, reading only
+    the weights of experts that some token chose.  ``params["layer"]``
+    indexes the expert weights, stacked over the scan's layers and read
+    in place."""
+    chosen = jax.nn.one_hot(idx, n_experts, dtype=jnp.float32)   # (N,k,E)
+    combine = (chosen * gate[..., None]).sum(1)                   # (N, E)
+    used = chosen.sum((0, 1)) > 0
+    active = jnp.flatnonzero(used, size=n_experts, fill_value=0)
+    n_active = used.sum(dtype=jnp.int32).reshape(1)
+    return kernel_ops.routed_expert_ffn(
+        x_flat, combine, active, n_active,
+        jnp.asarray(params["layer"], jnp.int32).reshape(1),
+        *(params.get(k) for k in EXPERT_WEIGHTS), activation=activation)
+
+
 # ---------------------------------------------------------------------------
 # mode bodies
 
@@ -195,15 +233,21 @@ def _moe_local(params, x_flat, *, n_experts, top_k, capacity_factor,
     cap = _capacity(n, top_k, n_experts, capacity_factor)
     idx, gate = _route(params["router"], x_flat, n_experts, top_k,
                        params.get("score_bias"), routed_scaling_factor)
-    held = None
-    if params["router"].shape[-1] != n_experts:
-        # an expert share: route over the router's width, keep the held
-        local = idx - expert_offset
-        held = (local >= 0) & (local < n_experts)
-        idx = jnp.where(held, local, 0)
-    buf, slot = _dispatch(x_flat, idx, n_experts, cap, held)
-    y = _expert_ffn(params, buf, activation)
-    out = _combine(y, idx, slot, gate)
+    if "layer" in params:
+        # the serving layer scan chose the routed FFN (routed_ffn) and hands
+        # over the stacked expert weights with the layer's index
+        out = _routed_expert_ffn(params, x_flat, idx, gate, n_experts,
+                                 activation)
+    else:
+        held = None
+        if params["router"].shape[-1] != n_experts:
+            # an expert share: route over the router's width, keep the held
+            local = idx - expert_offset
+            held = (local >= 0) & (local < n_experts)
+            idx = jnp.where(held, local, 0)
+        buf, slot = _dispatch(x_flat, idx, n_experts, cap, held)
+        y = _expert_ffn(params, buf, activation)
+        out = _combine(y, idx, slot, gate)
     if "shared" in params:
         out = out + apply_mlp(params["shared"], x_flat, activation)
     return out

@@ -80,12 +80,28 @@ def _moe_routing(cfg: ModelConfig) -> dict:
             "routed_scaling_factor": cfg.routed_scaling_factor}
 
 
+def _moe_dropless(cfg: ModelConfig, phase: str) -> bool:
+    # decode steps are few-token: dropless dispatch is free there and
+    # keeps speculative verification exact (no batch-dependent drops)
+    return cfg.moe_dropless or phase == "decode"
+
+
+def _routed_moe(cfg: ModelConfig, x: jax.Array, phase: str, mesh) -> bool:
+    """Whether a serving call's MoE layers take the routed expert FFN
+    (``moe.routed_ffn``, in ``local`` mode) on x (B, S, D).  The
+    cache-less (training) forward never does: its kernel has no VJP."""
+    b, s, _ = x.shape
+    return (cfg.is_moe
+            and moe_lib.select_moe_mode(cfg.n_experts, s, mesh) == "local"
+            and moe_lib.routed_ffn(b * s, cfg.n_experts, cfg.top_k,
+                                   cfg.router_experts,
+                                   _moe_dropless(cfg, phase)))
+
+
 def _apply_ffn(params: dict, cfg: ModelConfig, h: jax.Array, phase: str,
                mesh, use_moe: bool) -> jax.Array:
     if use_moe:
-        # decode steps are few-token: dropless dispatch is free there and
-        # keeps speculative verification exact (no batch-dependent drops)
-        cf = (float("inf") if (cfg.moe_dropless or phase == "decode")
+        cf = (float("inf") if _moe_dropless(cfg, phase)
               else cfg.capacity_factor)
         return moe_lib.apply_moe(params, h, n_experts=cfg.n_experts,
                                  top_k=cfg.top_k, activation=cfg.activation,
@@ -585,9 +601,27 @@ def forward_decoder(params: dict, cfg: ModelConfig, x: jax.Array, *,
         # update it in place per group.  Passing it as scan xs/ys instead
         # would materialize two extra full-cache copies (the sliced inputs
         # and the re-stacked outputs) — tens of GiB for 32k-context caches.
+        layers, experts = params["layers"], {}
+        if _routed_moe(cfg, x, phase, mesh):
+            # the routed expert FFN reads the stacked expert weights in
+            # place at the group's index: a scan-sliced operand of its
+            # kernel would be copied whole, every expert, every step
+            for i, g in enumerate(layers):
+                if cfg.moe_pattern[i]:
+                    experts[i] = {k: g["ffn"][k] for k in
+                                  moe_lib.EXPERT_WEIGHTS if k in g["ffn"]}
+            layers = tuple(
+                dict(g, ffn={k: v for k, v in g["ffn"].items()
+                             if k not in experts[i]}) if i in experts else g
+                for i, g in enumerate(layers))
+
         def body(carry, group_in):
             x, cache_layers = carry
             j, gparams = group_in
+            gparams = tuple(
+                dict(g, ffn=dict(g["ffn"], **experts[i],
+                                 layer=j - cfg.n_dense_groups))
+                if i in experts else g for i, g in enumerate(gparams))
             gcache = jax.tree.map(
                 lambda a: jax.lax.dynamic_index_in_dim(a, j, 0,
                                                        keepdims=False),
@@ -601,7 +635,7 @@ def forward_decoder(params: dict, cfg: ModelConfig, x: jax.Array, *,
 
         idx = jnp.arange(cfg.n_dense_groups, cfg.n_groups, dtype=jnp.int32)
         (x, new_layer_caches), pendings = jax.lax.scan(
-            body, (x, layer_caches), (idx, params["layers"]))
+            body, (x, layer_caches), (idx, layers))
         if dense_pendings:
             pendings = jax.tree.map(
                 lambda *a: jnp.concatenate([jnp.stack(a[:-1]), a[-1]]),

@@ -70,6 +70,7 @@ from repro.core.planner import (ParaSpecPlanner, Policy, Workload,
                                 kv_bytes_per_token)
 from repro.core.spec_decode import (record_acceptance, tree_n_nodes,
                                     tree_supported)
+from repro.models import moe as moe_lib
 from repro.models.transformer import (admit_sequence_paged, init_cache,
                                       init_paged_cache, release_slot_paged)
 from repro.obs import (NULL_REQUEST_TRACKER, FlightRecorder,
@@ -1174,10 +1175,21 @@ class ServingEngine:
                          "routed experts held per MoE layer, per model")
         width = reg.gauge("moe_router_experts",
                           "experts the router chooses among, per model")
-        for role, cfg in (("target", self.target_cfg),
-                          ("draft", self.draft_cfg)):
+        # 1 where the model's decode step runs the routed expert FFN
+        # (models/moe.py routed_ffn): the target verifies max_batch x
+        # (candidates + 1) tokens a step, the draft max_batch
+        routed = reg.gauge("moe_routed_ffn",
+                           "1 where a decode step reads only the experts "
+                           "its tokens chose, per model")
+        b = self.config.max_batch
+        for role, cfg, n in (("target", self.target_cfg,
+                              b * (self._cand_equiv() + 1)),
+                             ("draft", self.draft_cfg, b)):
             held.set(cfg.n_experts, model=role)
             width.set(cfg.router_experts, model=role)
+            routed.set(int(cfg.is_moe and moe_lib.routed_ffn(
+                n, cfg.n_experts, cfg.top_k, cfg.router_experts)),
+                model=role)
 
     def metrics(self) -> dict:
         """Structured observability snapshot: ``{"metrics": <registry

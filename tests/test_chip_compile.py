@@ -7,10 +7,12 @@ refuses fails here, with no chip.  The topology is described inside a
 module fixture (only one process may load the TPU compiler), and the
 tests skip where it cannot be described.
 """
+import json
 import math
 import os
 import re
 from functools import partial
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +20,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from conftest import model_config
 from repro.configs import PAIRS
 from repro.core.interleave import fused_verify_and_draft
 from repro.core.spec_decode import tree_spec
@@ -25,6 +28,7 @@ from repro.kernels import ops
 from repro.models import model as M
 from repro.models.transformer import init_cache, init_paged_cache
 
+ROOT = Path(__file__).resolve().parents[1]
 TARGET, DRAFT = PAIRS["mixtral-8x7b-v5e-pair"]
 K2, MOONLIGHT = PAIRS["kimi-k2-moonlight-v5e-pair"]
 # the kimi-k2.offline cell: 16 slots a half, a 1568-token cache (98 blocks)
@@ -179,12 +183,34 @@ def test_latent_kernel_compiles_at_kimi_k2_cell_shapes(one_chip):
 
 def test_kimi_pair_fused_step_compiles_and_fits_one_chip(one_chip):
     """The Kimi-K2 / Moonlight fused step at the cell's shapes: the
-    latent kernel is in it, and weights, caches and temporaries fit
-    16 GB with room for the second half's caches."""
+    latent kernel and the draft's routed expert FFN are in it, and
+    weights, caches and temporaries fit 16 GB with room for the second
+    half's caches."""
     compiled, weights = _compile_fused(one_chip, K2, MOONLIGHT, K2_BATCH,
                                        K2_MBS)
-    assert "%paged_mla_attention" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "%paged_mla_attention" in hlo and "%routed_expert_ffn" in hlo
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert weights < total < 0.9 * HBM_BYTES, (weights, total)
+
+
+# HLO instructions of the 8x22B cell's fused step, which has no routed
+# expert FFN: its target verifies 80 tokens over 8 experts (dense path),
+# its draft is dense.  A different count means that program changed.
+MIXTRAL_8X22B_FUSED_OPS = 4828
+
+
+def test_8x22b_fused_step_keeps_the_dense_expert_path(one_chip):
+    """The ``mixtral-8x22b.offline`` cell's fused step at its shapes (16
+    slots of 98 blocks) holds no routed expert FFN and keeps its op
+    count."""
+    doc = json.loads((ROOT / "bench/configs/mixtral-8x22b-v5e-pair.json")
+                     .read_text())
+    target, draft = (model_config(doc[k]) for k in ("target", "draft"))
+    compiled, _ = _compile_fused(one_chip, target, draft, 16, 98)
+    hlo = compiled.as_text()
+    ops_ = [l for l in hlo.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", l)]
+    assert "%routed_expert_ffn" not in hlo
+    assert len(ops_) == MIXTRAL_8X22B_FUSED_OPS

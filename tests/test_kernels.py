@@ -5,8 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import moe_ffn, ops, ref
 from repro.kernels.decode_attention import pages_per_step
+from repro.kernels.moe_ffn import block_f_for
 
 KEY = jax.random.PRNGKey(0)
 
@@ -178,23 +179,92 @@ def test_paged_matches_contiguous_decode():
                                rtol=1e-6, atol=1e-6)
 
 
+def _routing(case, e, k, n, d, key):
+    """(idx, gate) (N, k) of a routing case: ``sigmoid`` top-k on sigmoid
+    scores plus a std-0.5 bias (Moonlight's router); ``softmax`` top-k;
+    ``one`` every token's single choice the same expert; ``all`` each
+    expert chosen by some token."""
+    ks = jax.random.split(key, 3)
+    if case in ("sigmoid", "softmax"):
+        x = _rand((n, d), jnp.float32, ks[0])
+        logits = x @ (_rand((d, e), jnp.float32, ks[1]) * d ** -0.5)
+        if case == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, idx = jax.lax.top_k(
+                scores + 0.5 * _rand((e,), jnp.float32, ks[2]), k)
+            gate = jnp.take_along_axis(scores, idx, axis=-1)
+        else:
+            gate, idx = jax.lax.top_k(jax.nn.softmax(logits), k)
+        return idx, 2.4 * gate / gate.sum(-1, keepdims=True)
+    if case == "one":
+        idx = jnp.full((n, 1), e // 2 + 1, jnp.int32)
+    else:
+        idx = (jnp.arange(n)[:, None] * k + jnp.arange(k)[None]) % e
+    return idx, jax.random.uniform(ks[0], idx.shape, minval=0.1)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("e,c,d,f", [
-    (4, 128, 64, 256),
-    (2, 100, 128, 300),     # non-multiples (padding)
-    (8, 64, 32, 128),
+@pytest.mark.parametrize("case,e,k,n,d,f,gated", [
+    ("sigmoid", 64, 6, 16, 128, 1408, True),  # Moonlight's draft step
+    ("softmax", 8, 2, 80, 64, 256, True),      # every expert active
+    ("one", 8, 1, 16, 64, 256, True),          # a single active expert
+    ("all", 8, 2, 16, 64, 384, True),          # all E active
+    ("sigmoid", 16, 4, 8, 64, 256, False),     # ungated act(x Wu) Wd
 ])
-def test_moe_ffn(dtype, e, c, d, f):
-    ks = jax.random.split(KEY, 4)
-    buf = _rand((e, c, d), dtype, ks[0])
-    wg = _rand((e, d, f), dtype, ks[1]) * 0.1
-    wu = _rand((e, d, f), dtype, ks[2]) * 0.1
-    wd = _rand((e, f, d), dtype, ks[3]) * 0.1
-    got = ops.moe_ffn(buf, wg, wu, wd, block_c=64, block_f=128,
-                      interpret=True)
-    want = ref.moe_ffn_ref(buf, wg, wu, wd)
+def test_routed_expert_ffn(dtype, case, e, k, n, d, f, gated):
+    """The kernel, reading layer 1 of weights stacked over 2 layers,
+    against the per-token sum over chosen experts of that layer (f32).
+    The combine matrix and the active list are built here from the
+    routing, not by the model's code."""
+    _check_routed(ops.routed_expert_ffn, dtype, case, e, k, n, d, f, gated)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("gated", [True, False])
+def test_routed_expert_ffn_in_f_blocks(monkeypatch, dtype, gated):
+    """An expert too wide for the weight buffer runs in 128-wide F blocks
+    (4 here), each accumulating into the same output."""
+    monkeypatch.setattr(moe_ffn, "WEIGHT_BUFFER_BYTES", 2 * 3 * 64 * 128 * 2)
+    assert block_f_for(64, 512, 3, jnp.dtype(dtype).itemsize) == 128
+    # unjitted: the wrapper's jit cache would keep the patched block width
+    _check_routed(moe_ffn.routed_expert_ffn, dtype, "sigmoid", 16, 4, 8, 64,
+                  512, gated)
+
+
+def _check_routed(kernel, dtype, case, e, k, n, d, f, gated):
+    ks = jax.random.split(KEY, 5)
+    idx, gate = _routing(case, e, k, n, d, ks[0])
+    x = _rand((n, d), dtype, ks[1])
+    wg = (_rand((2, e, d, f), dtype, ks[2]) * d ** -0.5) if gated else None
+    wu = _rand((2, e, d, f), dtype, ks[3]) * d ** -0.5
+    wd = _rand((2, e, f, d), dtype, ks[4]) * f ** -0.5
+    ids, g = np.asarray(idx), np.asarray(gate)
+    combine = np.zeros((n, e), np.float32)
+    np.put_along_axis(combine, ids, g, axis=1)
+    used = np.unique(ids)
+    active = np.zeros(e, np.int32)
+    active[:len(used)] = used
+    assert (len(used) == e) == (case in ("softmax", "all"))
+    assert (len(used) == 1) == (case == "one")
+    got = kernel(x, jnp.asarray(combine), jnp.asarray(active),
+                 jnp.asarray([len(used)], jnp.int32),
+                 jnp.asarray([1], jnp.int32), wg, wu, wd, interpret=True)
+    want = ref.routed_expert_ffn_ref(x, idx, gate,
+                                     None if wg is None else wg[1], wu[1],
+                                     wd[1])
+    assert got.dtype == dtype and got.shape == (n, d)
     np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), **TOL[dtype])
+                               np.asarray(want), **TOL[dtype])
+
+
+@pytest.mark.parametrize("d,f,want", [
+    (2048, 1408, 1408),    # Moonlight: a whole expert a step (34.6 MB)
+    (7168, 2048, 512),     # Kimi-K2's experts: 4 F-blocks
+    (6144, 16384, 512),    # Mixtral-8x22B's: 32 F-blocks
+    (64, 300, 300),        # F not a 128-multiple: whole
+])
+def test_routed_expert_ffn_block_f(d, f, want):
+    assert block_f_for(d, f, 3, 2) == want
 
 
 @pytest.mark.parametrize("b,s,w", [(2, 64, 256), (1, 128, 100), (4, 32, 512)])
