@@ -1,13 +1,14 @@
 """End-to-end serving driver (deliverable b): a Poisson request trace
-through the full SpecOffload engine — offline placement, zig-zag prefill,
-dual-batch interleaved decode with speculative verification, and the
+through the SpecOffload serving path — prefill on admission, dual-batch
+interleaved decode with speculative verification, and the
 continuous-batching scheduler (EOS retirement + mid-flight admission).
 
     PYTHONPATH=src python examples/serve_spec_offload.py [--arch mixtral-8x7b]
 
 Uses the reduced config of the chosen architecture so it runs on CPU; the
-pipeline structure (placement plan, interleaved batches, rollback, slot
-scheduler) is the production one.
+pipeline structure (interleaved batches, rollback, slot scheduler) is the
+production one.  ``python -m repro.launch.serve --plan`` prints the
+placement plan and ParaSpec policy for a configuration.
 """
 import argparse
 
@@ -35,11 +36,6 @@ print(f"target: {tcfg.name} (reduced) | draft: {dcfg.name}")
 eng = ServingEngine(tcfg, dcfg, ENV1,
                     config=SchedulerConfig(max_batch=2, n_cand=3))
 eng.init_from_seed(0)
-
-plan = eng.engine.placement
-print("placement:", {e.name: e.tier for e in plan.entries[:4]}, "...")
-for note in plan.notes:
-    print("  note:", note)
 
 ds = synthetic_dataset("samsum", n_prompts=args.requests,
                        vocab=tcfg.vocab_size)

@@ -1,99 +1,123 @@
-"""SpecOffloadEngine — the paper's full system (§3): offline placement +
-online planning + the two-phase interleaved pipeline.
+"""SpecOffloadEngine — the round runner between the continuous-batching
+scheduler (:mod:`repro.serving.engine`) and the fused programs
+(:mod:`repro.core.interleave`).
 
-Usage (see examples/serve_spec_offload.py)::
+It holds both models' weights and builds every jitted program once:
+prefill, the chain and tree draft-only programs, the chain and tree fused
+verify+draft programs, and the chain's rollback.  ``jax.jit``'s static
+arguments key compiles by ``n_cand`` / branching, so one runner serves
+either speculation mode.
 
-    engine = SpecOffloadEngine(target_cfg, draft_cfg, hw=ENV1)
-    engine.load(target_params, draft_params)
-    out = engine.generate(prompts, gen_len=64)
+* :meth:`prefill_batch` — prefill of a prompt batch (§4.1.1) into a fresh
+  :class:`BatchState` (target + draft caches, first greedy token staged
+  in ``t_next``).
+* :meth:`warmup` — slot t_0 of the paper's Figure 4: draft candidates for
+  one batch so it can be verified next round.
+* :meth:`decode_round` — one dual-batch rotation round (§4.1.2): verify
+  the batch that holds drafts while drafting for the other, one fused
+  XLA program; returns the verified batch's per-sequence tokens.  The
+  caller owns the rotation (swap the two states between calls) and may
+  mutate per-slot state *between* rounds — the verified batch's
+  ``drafts`` is ``None`` on return, the safe window for a scheduler to
+  retire finished sequences and splice new ones into freed cache slots.
 
-Stepwise API
-------------
-``generate()`` is a convenience wrapper over three explicit phases, each
-usable on its own (the continuous-batching scheduler in
-:mod:`repro.serving.engine` drives them directly):
-
-* :meth:`prefill_batch` — zig-zag microbatched prefill (§4.1.1) of a
-  prompt batch into a fresh :class:`BatchState` (target + draft caches,
-  first greedy token staged in ``t_next``).
-* :meth:`decode_round` — one dual-batch rotation round (§4.1.2) via
-  :class:`repro.core.interleave.InterleavedPipeline`; returns the
-  verified batch's per-sequence tokens.
-* :meth:`finalize` — assemble the per-round emission log of the two
-  interleaved batches into a dense ``(B, gen_len)`` token array.
-
-Phases
-------
-* **Prefill** (§4.1.1) — zig-zag microbatching: the prompt batch is split
-  into ``bs_prefill`` chunks; each chunk runs a full prefill while the
-  engine keeps only the streamed working set resident.  KV is then handed
-  to the decode phase (host tier in the offloaded configuration).
-* **Decode** (§4.1.2) — dual-batch rotation via
-  :class:`repro.core.interleave.InterleavedPipeline`.
-
-The engine is hardware-agnostic: on the CPU container it runs the real
-algorithm end-to-end at small scale; placement/planner decisions use the
-configured :class:`HardwareSpec`.
+All shapes inside a round are fixed by ``(batch, n_cand)`` (or the tree
+shape), so the fused program compiles exactly once however many
+sequences retire or join across rounds (``trace_counts`` keeps the tally).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+import functools
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core.interleave import (BatchState, InterleavedPipeline,
-                                   RoundOutput)
-from repro.core.placement import PlacementPlan, plan_placement
-from repro.core.planner import ParaSpecPlanner, Policy, Workload
+from repro.core.interleave import (BatchState, RoundOutput,
+                                   fused_tree_verify_and_draft,
+                                   fused_verify_and_draft)
+from repro.core.spec_decode import (draft_generate, draft_tree_generate,
+                                    rollback_draft)
 from repro.models import model as M
 from repro.models.transformer import init_cache
 from repro.obs import NULL_OBS
-from repro.sim.hardware import ENV1, HardwareSpec
 
 
 def required_cache_len(prompt_len: int, gen_len: int, n_cand: int) -> int:
     """Per-sequence KV capacity for a decode of ``gen_len`` tokens: the
     last speculative round can overshoot the target length, and the draft
     cache briefly holds ``n_cand + 1`` uncommitted positions before
-    rollback.  Shared by generate() and the serving scheduler so their
-    capacity checks can never diverge."""
+    rollback.  Shared by the serving scheduler's admission checks and the
+    benchmark's cache sizing so the two can never diverge."""
     return prompt_len + gen_len + 3 * (n_cand + 1) + 4
 
 
-@dataclass
-class GenerationResult:
-    tokens: np.ndarray            # (B, gen_len)
-    rounds: int
-    accept_counts: list
-    policy: Policy
-    placement: PlacementPlan
-
-
 class SpecOffloadEngine:
+    """Dual-batch rotation over two resident models, one round at a time.
+
+    ``trace_counts`` records how many times each jitted entry point was
+    (re)traced; a scheduler that keeps shapes stable sees
+    ``trace_counts['fused'] == 1`` for the whole serving lifetime.
+    """
+
     def __init__(self, target_cfg: ModelConfig, draft_cfg: ModelConfig,
-                 hw: HardwareSpec = ENV1, policy: Policy | None = None,
-                 mesh=None, obs=None):
+                 obs=None):
         self.tcfg = target_cfg
         self.dcfg = draft_cfg
-        self.hw = hw
-        self.mesh = mesh
         self.obs = obs if obs is not None else NULL_OBS
-        self.policy = policy
-        self.placement = plan_placement(target_cfg, draft_cfg, hw)
         self.tp = None
         self.dp = None
+        self.trace_counts = {"fused": 0, "draft": 0, "rollback": 0}
+        self._exported_traces = {k: 0 for k in self.trace_counts}
         self._prefill = jax.jit(M.prefill, static_argnums=(1,),
                                 static_argnames=("mesh",))
-        self._pipe: InterleavedPipeline | None = None
+        self._fused = jax.jit(
+            self._counted("fused", fused_verify_and_draft),
+            static_argnames=("target_cfg", "draft_cfg", "n_cand", "mesh"))
+        self._fused_tree = jax.jit(
+            self._counted("fused", fused_tree_verify_and_draft),
+            static_argnames=("target_cfg", "draft_cfg", "branching",
+                             "mesh"))
+        self._draft = jax.jit(
+            self._counted("draft", draft_generate),
+            static_argnames=("cfg", "n_cand", "mesh"))
+        self._draft_tree = jax.jit(
+            self._counted("draft", draft_tree_generate),
+            static_argnames=("cfg", "branching", "mesh", "collect_logits"))
+        # tree mode commits both caches inside the fused program
+        self._rollback = jax.jit(
+            self._counted("rollback", rollback_draft),
+            static_argnames=("cfg",))
+
+    def _counted(self, name, fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            self.trace_counts[name] += 1   # runs only while tracing
+            return fn(*args, **kwargs)
+        return wrapper
+
+    def export_trace_counts(self, registry) -> None:
+        """Sync ``trace_counts`` into ``pipeline_traces_total{entry=...}``
+        counters (delta-based: safe to call repeatedly).  A shape-stable
+        serving run must report ``entry="fused"`` == 1 through this path
+        (regression-tested in tests/test_obs.py)."""
+        ctr = registry.counter(
+            "pipeline_traces_total",
+            "jit (re)traces per pipeline entry point; fused must stay 1")
+        for entry, n in self.trace_counts.items():
+            delta = n - self._exported_traces[entry]
+            if delta:
+                ctr.inc(delta, entry=entry)
+                self._exported_traces[entry] = n
+            elif n == 0:
+                ctr.inc(0, entry=entry)   # materialize the zero series
 
     # ------------------------------------------------------------------
     def load(self, target_params, draft_params):
         self.tp = target_params
         self.dp = draft_params
-        self._pipe = None
 
     def init_from_seed(self, seed: int = 0):
         """Random weights from ``seed``, built under ``jax.jit`` one model
@@ -106,42 +130,9 @@ class SpecOffloadEngine:
         tp = jax.block_until_ready(init(self.tcfg, k1))
         self.load(tp, init(self.dcfg, k2))
 
-    def plan(self, prompt_len: int, gen_len: int,
-             accept_prob: float = 0.7, occupancy: float = 1.0) -> Policy:
-        if self.policy is not None:
-            return self.policy
-        planner = ParaSpecPlanner(self.tcfg, self.dcfg, self.hw,
-                                  obs=self.obs)
-        rep = planner.search(Workload(prompt_len, gen_len, accept_prob,
-                                      occupancy))
-        self.policy = rep.policy
-        return self.policy
-
     # ------------------------------------------------------------------
-    def _prefill_zigzag(self, params, cfg, tokens: jax.Array,
-                        bs_prefill: int, max_len: int):
-        """Microbatched prefill (zig-zag §4.1.1): the batch is processed in
-        ``bs_prefill`` chunks so only one chunk's activations + KV are live
-        on the accelerator at a time; chunk caches are then concatenated
-        (the paper ships them to host memory between chunks)."""
-        b = tokens.shape[0]
-        last_logits, caches = [], []
-        for i in range(0, b, bs_prefill):
-            chunk = tokens[i:i + bs_prefill]
-            c = init_cache(cfg, chunk.shape[0], max_len)
-            lg, c = self._prefill(params, cfg, chunk, c)
-            last_logits.append(lg)
-            caches.append(c)
-        if len(caches) == 1:
-            return last_logits[0], caches[0]
-        return jnp.concatenate(last_logits, 0), _concat_caches(caches)
-
-    # ------------------------------------------------------------------
-    # stepwise API
-
-    def prefill_batch(self, prompts: jax.Array, max_len: int,
-                      bs_prefill: int | None = None) -> BatchState:
-        """Zig-zag prefill of a ``(B, L)`` prompt batch into a fresh
+    def prefill_batch(self, prompts: jax.Array, max_len: int) -> BatchState:
+        """Prefill of a ``(B, L)`` prompt batch into a fresh
         :class:`BatchState` with KV capacity ``max_len`` per sequence.
 
         The first greedy token (argmax over the prefill's last logits) is
@@ -149,96 +140,116 @@ class SpecOffloadEngine:
         as a target-only greedy decode would start.
         """
         assert self.tp is not None, "call load()/init_from_seed() first"
-        bs_prefill = bs_prefill or max(1, prompts.shape[0])
+        b = prompts.shape[0]
         with self.obs.tracer.span("prefill", "zigzag_prefill",
                                   cat="device") as sp:
-            lg, tc = self._prefill_zigzag(self.tp, self.tcfg, prompts,
-                                          bs_prefill, max_len)
-            _, dc = self._prefill_zigzag(self.dp, self.dcfg, prompts,
-                                         bs_prefill, max_len)
+            lg, tc = self._prefill(self.tp, self.tcfg, prompts,
+                                   init_cache(self.tcfg, b, max_len))
+            _, dc = self._prefill(self.dp, self.dcfg, prompts,
+                                  init_cache(self.dcfg, b, max_len))
             sp.fence((lg, tc, dc))
-            sp.set("batch", int(prompts.shape[0]))
+            sp.set("batch", int(b))
             sp.set("prompt_len", int(prompts.shape[1]))
         t0 = jnp.argmax(lg, -1)
         return BatchState(target_cache=tc, draft_cache=dc, t_next=t0,
                           drafts=None, draft_pendings=None,
                           emitted=[(np.asarray(t0)[:, None], 1)])
 
-    def pipeline(self, n_cand: int, tree=None) -> InterleavedPipeline:
-        """The (cached) dual-batch rotation pipeline for ``n_cand`` —
-        or, when ``tree`` (a branching tuple) is given, the tree-mode
-        pipeline with that speculation-tree shape."""
-        assert self.tp is not None, "call load()/init_from_seed() first"
-        tree = tuple(tree) if tree is not None else None
-        if (self._pipe is None or self._pipe.n_cand != n_cand
-                or self._pipe.tree != tree):
-            self._pipe = InterleavedPipeline(self.tp, self.tcfg, self.dp,
-                                             self.dcfg, n_cand, self.mesh,
-                                             obs=self.obs, tree=tree)
-        return self._pipe
+    def warmup(self, state: BatchState, n_cand: int, tree=None) -> None:
+        """Slot t_0 (Fig. 4): draft candidates for ``state`` so the next
+        round can verify it.  No-op if drafts are already staged."""
+        if state.drafts is not None:
+            return
+        with self.obs.tracer.span("draft_generate", "warmup",
+                                  cat="device") as sp:
+            if tree is not None:
+                d, _, dc = self._draft_tree(self.dp, self.dcfg,
+                                            state.draft_cache,
+                                            state.t_next, tree)
+                pend = None
+            else:
+                d, _, dc, pend = self._draft(self.dp, self.dcfg,
+                                             state.draft_cache,
+                                             state.t_next, n_cand)
+            sp.fence(d)
+        state.drafts, state.draft_cache, state.draft_pendings = d, dc, pend
+
+    def _fused_call(self, verify: BatchState, gen: BatchState, n_cand: int,
+                    tree) -> tuple:
+        """The fused program for the mode and its arguments."""
+        assert verify.drafts is not None, "verify batch has no staged drafts"
+        vstate = {"target_cache": verify.target_cache,
+                  "t_next": verify.t_next, "drafts": verify.drafts}
+        dstate = {"draft_cache": gen.draft_cache, "t_next": gen.t_next}
+        if tree is None:
+            fused, shape = self._fused, n_cand
+        else:
+            vstate["draft_cache"] = verify.draft_cache
+            fused, shape = self._fused_tree, tree
+        return fused, (self.tp, self.tcfg, self.dp, self.dcfg, vstate,
+                       dstate, shape)
+
+    def lower_fused(self, verify: BatchState, gen: BatchState, n_cand: int,
+                    tree=None):
+        """The fused round lowered for these two states without running
+        it (``.compile().as_text()`` shows the program each round
+        executes).  Stages ``verify``'s drafts first if it has none."""
+        self.warmup(verify, n_cand, tree)
+        fused, args = self._fused_call(verify, gen, n_cand, tree)
+        return fused.lower(*args)
 
     def decode_round(self, verify: BatchState, gen: BatchState,
                      n_cand: int, record: bool = True,
                      tree=None) -> RoundOutput:
-        """One rotation round: verify ``verify``, draft for ``gen``.
-        Swap the two states between calls to rotate roles; see
-        :meth:`InterleavedPipeline.step` for the slot-surgery window."""
-        pipe = self.pipeline(n_cand, tree=tree)
-        pipe.warmup(verify)
-        return pipe.step(verify, gen, record=record)
+        """One rotation round: verify ``verify``'s staged drafts (drafting
+        them first if none are staged) while drafting fresh candidates for
+        ``gen``, in one fused XLA program; ``tree`` (a branching tuple)
+        selects tree speculation.
 
-    def finalize(self, states: list, gen_len: int) -> tuple:
-        """Assemble the two interleaved batches' emission logs into a
-        dense ``(B_total, gen_len)`` array (+ per-round accept counts)."""
-        widths = [int(np.asarray(st.emitted[0][0]).shape[0])
-                  for st in states]
-        out = np.zeros((sum(widths), gen_len), np.int32)
-        accepts = []
-        row0 = 0
-        for st, width in zip(states, widths):
-            fills = [list() for _ in range(width)]
-            for toks, n in st.emitted:
-                toks = np.asarray(toks)
-                n = np.asarray(n) + np.zeros(toks.shape[0], np.int32)
-                for r in range(toks.shape[0]):
-                    fills[r].extend(toks[r, :int(n[r])].tolist())
-                if toks.shape[1] > 1:
-                    accepts.append(n - 1)
-            for r, f in enumerate(fills):
-                out[row0 + r] = (f + [0] * gen_len)[:gen_len]
-            row0 += width
-        return out, accepts
-
-    # ------------------------------------------------------------------
-    def generate(self, prompts: jax.Array, gen_len: int, n_cand: int = 4,
-                 max_len: int | None = None) -> GenerationResult:
-        """prompts (B, L) int32, B split into the two interleaved batches.
-
-        Convenience wrapper: prefill both halves, rotate decode rounds
-        until every sequence has ``gen_len`` tokens, finalize."""
-        assert self.tp is not None, "call load()/init_from_seed() first"
-        b, length = prompts.shape
-        pol = self.policy or Policy(bs_prefill=max(1, b // 2),
-                                    bs_decode=max(1, b // 2),
-                                    bs_draft=max(1, b // 2), n_cand=n_cand)
-        m = pol.n_cand
-        max_len = max_len or required_cache_len(length, gen_len, m)
-
-        half = b // 2
-        states = [self.prefill_batch(bt, max_len, pol.bs_prefill)
-                  for bt in (prompts[:half], prompts[half:])]
-
-        pipe = self.pipeline(m)
-        s0, s1, rounds = pipe.run(states, gen_len)
-
-        out, accepts = self.finalize([s0, s1], gen_len)
-        return GenerationResult(out, rounds, accepts, pol, self.placement)
-
-
-def _concat_caches(caches):
-    """Concat per-chunk caches over the batch axis (axis 1 for stacked
-    layer leaves, axis 0 for 'pos')."""
-    layers = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=1),
-                          *[c["layers"] for c in caches])
-    pos = jnp.concatenate([c["pos"] for c in caches], axis=0)
-    return {"layers": layers, "pos": pos}
+        Mutates both states in place; on return ``verify.drafts is None``
+        (the safe window for slot surgery) and ``gen`` holds new drafts.
+        ``record=False`` skips appending to ``verify.emitted`` — use it
+        when the caller does its own per-slot bookkeeping, so a
+        long-running server doesn't grow the emitted log unboundedly.
+        """
+        self.warmup(verify, n_cand, tree)
+        assert gen.drafts is None, "gen batch already holds drafts"
+        t_round0 = time.perf_counter()
+        tr = self.obs.tracer
+        # one XLA program verifies batch V and drafts for batch D
+        with tr.span("target_verify", "verify(fused)", cat="device") as sp:
+            fused, args = self._fused_call(verify, gen, n_cand, tree)
+            vout, dout = fused(*args)
+            sp.fence((vout, dout))
+        verify.target_cache = vout["target_cache"]
+        if tree is not None:
+            # batch V's draft cache was compacted to the accepted path
+            # inside the fused program — no separate rollback dispatch.
+            verify.draft_cache = vout["draft_cache"]
+        else:
+            # batch V: commit + roll its draft cache back to acceptance
+            with tr.span("rollback", "rollback", cat="device") as rb:
+                verify.draft_cache = rb.fence(self._rollback(
+                    self.dcfg, verify.draft_cache, verify.draft_pendings,
+                    vout["n_emitted"]))
+        t_dispatched = time.perf_counter()
+        verify.t_next = vout["t_next"]
+        verify.drafts, verify.draft_pendings = None, None
+        # Waits for the fused program, then copies its outputs to the
+        # host.  Batch D's fresh drafts are stashed, and the round's
+        # spent device arrays released, inside the span too: that host
+        # work runs while the device idles, so it belongs to this phase.
+        with tr.span("d2h", "outputs"):
+            tokens = np.asarray(vout["tokens"])
+            n_emitted = np.asarray(vout["n_emitted"])
+            n_accept = np.asarray(vout["n_accept"])
+            gen.drafts = dout["drafts"]
+            gen.draft_cache = dout["draft_cache"]
+            gen.draft_pendings = dout.get("pendings")
+            del vout, dout
+        out = RoundOutput(tokens=tokens, n_emitted=n_emitted,
+                          n_accept=n_accept, t0=t_round0,
+                          t_dispatched=t_dispatched, t1=time.perf_counter())
+        if record:
+            verify.emitted.append((out.tokens, out.n_emitted))
+        return out

@@ -284,13 +284,21 @@ def spec_round(target_params, target_cfg: ModelConfig, target_cache,
                             n_cand + 1)
     draft_cache = rollback_draft(draft_cfg, draft_cache, pendings, n_commit)
 
-    # output slots: accepted drafts then the bonus token at slot ``a``
-    out = jnp.where(jnp.arange(n_cand)[None, :] < a[:, None], drafts, 0)
-    out = jnp.concatenate([out, jnp.zeros_like(a[:, None])], axis=1)
-    out = jax.vmap(lambda row, i, t: row.at[i].set(t))(out, a, nxt)
-    return {"tokens": out, "n_emitted": a + 1, "t_next": nxt,
+    out, n_emitted = chain_output_slots(drafts, a, nxt)
+    return {"tokens": out, "n_emitted": n_emitted, "t_next": nxt,
             "target_cache": target_cache, "draft_cache": draft_cache,
             "n_accept": a}
+
+
+def chain_output_slots(drafts: jax.Array, a: jax.Array, nxt: jax.Array):
+    """A chain round's output slots: the ``a`` accepted drafts, then the
+    bonus token ``nxt`` at slot ``a``, zeros after.  Returns ``(tokens
+    (B, m+1), n_emitted (B,) = a + 1)``."""
+    m = drafts.shape[1]
+    out = jnp.where(jnp.arange(m)[None, :] < a[:, None], drafts, 0)
+    out = jnp.concatenate([out, jnp.zeros_like(a[:, None])], axis=1)
+    out = jax.vmap(lambda row, i, t: row.at[i].set(t))(out, a, nxt)
+    return out, a + 1
 
 
 # ---------------------------------------------------------------------------

@@ -2,8 +2,9 @@
 
 The paper's workload is offline batch inference: fixed padded waves run to
 the longest ``max_new_tokens``.  This engine replaces that with a
-**continuous-batching scheduler** over the stepwise engine core
-(:meth:`SpecOffloadEngine.prefill_batch` / :meth:`decode_round`):
+**continuous-batching scheduler** over the round runner
+(:class:`repro.core.pipeline.SpecOffloadEngine`: :meth:`prefill_batch`
+on admission, :meth:`decode_round` once per iteration):
 
 * Each of the two interleaved half-batches is a fixed-shape
   :class:`BatchState` of ``max_batch`` **slots**.  The fused
@@ -15,10 +16,10 @@ the longest ``max_new_tokens``.  This engine replaces that with a
   reaches its own ``max_new_tokens``; nothing waits for the longest
   request in a wave.
 * Freed slots are refilled **mid-flight** at round boundaries: a queued
-  request is prefilled on admission via the zig-zag path (§4.1.1) and
-  its target+draft KV is spliced into the freed cache slot.  Admission
-  happens only while the half's ``drafts`` are un-staged (right after it
-  was verified), so speculative state always covers the slot contents
+  request is prefilled on admission (§4.1.1) and its target+draft KV is
+  spliced into the freed cache slot.  Admission happens only while the
+  half's ``drafts`` are un-staged (right after it was verified), so
+  speculative state always covers the slot contents
   and per-sequence outputs stay token-identical to a target-only greedy
   decode (the losslessness invariant, tested in
   ``tests/test_scheduler.py``).
@@ -152,7 +153,6 @@ class SchedulerConfig:
     pad_id: int = 0
     max_len: int | None = None    # per-slot KV capacity; derived from the
                                   # queue at first run() when None
-    prefill_chunk: int = 8        # zig-zag microbatch size on admission
     replan_threshold: float | None = None  # occupancy drift that triggers
                                   # an online ParaSpec re-search (None: off)
     replan_accept_drift: float | None = None  # measured-acceptance drift
@@ -332,24 +332,15 @@ def latency_percentiles(done: list, attr: str = "latency_s",
 class ServingEngine:
     """Continuous-batching front door; see the module docstring.
 
-    ``n_cand``/``batch_size``/``eos_id`` are legacy shortcuts — they seed
-    a default :class:`SchedulerConfig` when ``config`` is not given.
-    """
+    ``hw`` is the hardware the online re-planner models."""
     target_cfg: ModelConfig
     draft_cfg: ModelConfig
     hw: HardwareSpec = ENV1
-    n_cand: int = 4
-    batch_size: int = 8           # per interleaved half-batch x2 total
-    eos_id: int = -1              # -1: never stop early
-    config: SchedulerConfig | None = None
+    config: SchedulerConfig = field(default_factory=SchedulerConfig)
     engine: SpecOffloadEngine = field(init=False)
     _queue: list = field(default_factory=list)
 
     def __post_init__(self):
-        if self.config is None:
-            self.config = SchedulerConfig(max_batch=self.batch_size,
-                                          n_cand=self.n_cand,
-                                          eos_id=self.eos_id)
         if self.config.spec_tree is not None:
             self.config.spec_tree = tuple(self.config.spec_tree)
             for name, cfg in (("target", self.target_cfg),
@@ -390,7 +381,7 @@ class ServingEngine:
                                        on_violation=self._on_slo_violation)
                             if self._slos else None)
         self.engine = SpecOffloadEngine(self.target_cfg, self.draft_cfg,
-                                        self.hw, obs=self.obs)
+                                        obs=self.obs)
         self._splice = jax.jit(_splice_slot)
         self._admit_paged = jax.jit(admit_sequence_paged,
                                     static_argnums=(0,))
@@ -580,10 +571,8 @@ class ServingEngine:
             # Park a 1-token dummy sequence in every slot: shapes are fixed
             # forever, real requests are spliced in by _admit().
             dummy = np.zeros((cfg.max_batch, 1), np.int32)
-            self._halves = [
-                self.engine.prefill_batch(dummy, self._max_len,
-                                          cfg.max_batch)
-                for _ in range(2)]
+            self._halves = [self.engine.prefill_batch(dummy, self._max_len)
+                            for _ in range(2)]
         self._slots = [[_Slot() for _ in range(cfg.max_batch)]
                        for _ in range(2)]
 
@@ -695,8 +684,7 @@ class ServingEngine:
             pt0 = time.perf_counter()
             with self.obs.tracer.span("admit", "admit") as asp:
                 st = self.engine.prefill_batch(prompt[None, :],
-                                               self._max_len,
-                                               cfg.prefill_chunk)
+                                               self._max_len)
                 if cfg.paged:
                     block_ids, n_shared = grant
                     row = np.zeros(self._max_len // cfg.block_size,
@@ -1156,9 +1144,7 @@ class ServingEngine:
         """Bring scrape-time gauges/counters up to date: pipeline trace
         counts, allocator block states, lifetime totals."""
         reg = self.obs.metrics
-        pipe = self.engine._pipe
-        if pipe is not None:
-            pipe.export_trace_counts(reg)
+        self.engine.export_trace_counts(reg)
         if self._allocs is not None:
             for a in self._allocs:
                 a.export_gauges(reg)
@@ -1345,26 +1331,23 @@ class ServingEngine:
     def lower_fused(self):
         """The next fused verify+draft round lowered for this engine's
         slot shapes, without running it (see
-        :meth:`InterleavedPipeline.lower_fused`)."""
+        :meth:`SpecOffloadEngine.lower_fused`)."""
         if self._halves is None:
             raise ValueError("lower_fused() before any round was served")
         v = self._v
-        pipe = self.engine.pipeline(self.config.n_cand,
-                                    tree=self.config.spec_tree)
-        pipe.warmup(self._halves[v])
-        return pipe.lower_fused(self._halves[v], self._halves[1 - v])
+        return self.engine.lower_fused(self._halves[v], self._halves[1 - v],
+                                       self.config.n_cand,
+                                       tree=self.config.spec_tree)
 
     def stats(self) -> dict:
         """Engine-level serving metrics."""
-        pipe = self.engine._pipe
         return {
             "rounds": self._rounds,
             "tokens_out": self._tokens_out,
             "wall_s": self._wall_s,
             "mean_occupancy": self._occ_sum / max(1, self._rounds),
             "tok_per_s": self._tokens_out / max(self._wall_s, 1e-9),
-            "fused_compiles": 0 if pipe is None
-            else pipe.trace_counts["fused"],
+            "fused_compiles": self.engine.trace_counts["fused"],
             "rejected": self.rejected_total,
             "preempted": self.preempted_total,
             "replans": len(self.replan_events),

@@ -186,8 +186,7 @@ def test_stall_counted_in_its_phase(phase):
     history, moves that phase's stall counter alone and raises a
     ``stall`` trigger in the flight recorder."""
     se = _long_engine(n_req=24)
-    pipe = se.engine.pipeline(se.config.n_cand)
-    fused, armed = pipe._fused, [False]
+    fused, armed = se.engine._fused, [False]
 
     def due():
         fire, armed[0] = armed[0], False
@@ -209,7 +208,7 @@ def test_stall_counted_in_its_phase(phase):
         return {p: _counter(se, f"serve_stall_{p}_seconds_total")
                 for p in ("dispatch", "fetch", "host")}
 
-    pipe._fused, se.emit_hook = slow_fused, emit_hook
+    se.engine._fused, se.emit_hook = slow_fused, emit_hook
     while se.has_work() and se.stats()["rounds"] < 70:
         se.run_step()
     before, n_triggers, armed[0] = stalls(), len(se.recorder.triggers), True

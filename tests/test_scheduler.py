@@ -21,7 +21,8 @@ def served():
     at their own lengths and queued requests join freed slots mid-run."""
     tcfg = tiny_config(("attn",))
     dcfg = tiny_draft_config()
-    se = ServingEngine(tcfg, dcfg, n_cand=2, batch_size=2)
+    se = ServingEngine(tcfg, dcfg,
+                       config=SchedulerConfig(max_batch=2, n_cand=2))
     se.init_from_seed(0)
     rng = np.random.default_rng(0)
     reqs = []
@@ -67,9 +68,8 @@ def test_fused_step_compiles_once(served):
     """Slot retirement/admission must never change the fused step's
     shapes — one trace for the whole serving lifetime."""
     se, _, _ = served
-    pipe = se.engine.pipeline(se.config.n_cand)
-    assert pipe.trace_counts["fused"] == 1
-    assert pipe.trace_counts["rollback"] == 1
+    assert se.engine.trace_counts["fused"] == 1
+    assert se.engine.trace_counts["rollback"] == 1
 
 
 def test_metrics_recorded(served):
@@ -91,7 +91,8 @@ def test_eos_early_retirement(jitted):
     stream still matches the greedy reference up to (and including) it."""
     tcfg = tiny_config(("attn",))
     dcfg = tiny_draft_config()
-    se = ServingEngine(tcfg, dcfg, n_cand=2, batch_size=2)
+    se = ServingEngine(tcfg, dcfg,
+                       config=SchedulerConfig(max_batch=2, n_cand=2))
     se.init_from_seed(0)
     rng = np.random.default_rng(42)
     prompt = rng.integers(0, 61, 9).astype(np.int32)

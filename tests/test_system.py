@@ -1,5 +1,5 @@
-"""System-level behaviour: the full SpecOffloadEngine, the serving engine,
-the planner/simulator against paper claims, training convergence."""
+"""System-level behaviour: the serving engine end to end, the
+planner/simulator against paper claims, training convergence."""
 import dataclasses
 
 import jax
@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 from repro.configs.base import MISTRAL_7B, MIXTRAL_8X7B, MIXTRAL_8X22B
-from repro.core.pipeline import SpecOffloadEngine
 from repro.core.planner import ParaSpecPlanner, Policy, Workload
 from repro.models import model as M
 from repro.sim.hardware import ENV1, ENV2
@@ -19,23 +18,34 @@ from conftest import greedy_reference, tiny_config, tiny_draft_config
 
 def test_engine_end_to_end_lossless(jitted):
     """The dual-batch interleaved engine == pure greedy decoding."""
+    from repro.serving.engine import (SchedulerConfig, ServeRequest,
+                                      ServingEngine)
     tcfg = tiny_config(("attn",))
     dcfg = tiny_draft_config()
-    eng = SpecOffloadEngine(tcfg, dcfg)
-    eng.init_from_seed(0)
+    se = ServingEngine(tcfg, dcfg,
+                       config=SchedulerConfig(max_batch=2, n_cand=3))
+    se.init_from_seed(0)
     B, L, G = 4, 8, 10
     prompts = jax.random.randint(jax.random.PRNGKey(3), (B, L), 0, 61)
-    res = eng.generate(prompts, gen_len=G, n_cand=3)
-    ref = greedy_reference(eng.tp, tcfg, prompts, G, 64, jitted)
-    assert (res.tokens == np.asarray(ref)).all()
-    assert res.rounds > 0
+    reqs = [ServeRequest(i, np.asarray(prompts[i]), max_new_tokens=G)
+            for i in range(B)]
+    for r in reqs:
+        se.submit(r)
+    assert len(se.run()) == B
+    ref = np.asarray(greedy_reference(se.engine.tp, tcfg, prompts, G, 64,
+                                      jitted))
+    for i, r in enumerate(reqs):
+        assert (r.result == ref[i]).all(), f"rid {r.rid}"
+    assert se.stats()["rounds"] > 0
 
 
 def test_serving_engine_drains_queue():
-    from repro.serving.engine import ServeRequest, ServingEngine
+    from repro.serving.engine import (SchedulerConfig, ServeRequest,
+                                      ServingEngine)
     tcfg = tiny_config(("attn",))
     dcfg = tiny_draft_config()
-    se = ServingEngine(tcfg, dcfg, n_cand=2, batch_size=2)
+    se = ServingEngine(tcfg, dcfg,
+                       config=SchedulerConfig(max_batch=2, n_cand=2))
     se.init_from_seed(0)
     rng = np.random.default_rng(0)
     for i in range(5):  # deliberately not a multiple of the wave size

@@ -8,8 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.interleave import InterleavedPipeline
-from repro.core.pipeline import SpecOffloadEngine
 from repro.core.spec_decode import (MAX_TREE_NODES, acceptance_pmf,
                                     acceptance_pmf_tree, expected_generated,
                                     expected_generated_tree,
@@ -258,28 +256,30 @@ def M_params(cfg, seed):
 
 def test_tree_pipeline_single_compile_lossless(jitted):
     tcfg = tiny_config(("attn",))
-    dcfg = _attn_draft()
-    eng = SpecOffloadEngine(tcfg, dcfg)
-    eng.init_from_seed(0)
+    se = ServingEngine(tcfg, _attn_draft(),
+                       config=SchedulerConfig(max_batch=2, spec_tree=(3, 2)))
+    se.init_from_seed(0)
     b, L, gen = 4, 6, 10
     prompts = jax.random.randint(jax.random.PRNGKey(3), (b, L), 0,
                                  tcfg.vocab_size)
-    ref = np.asarray(greedy_reference(eng.tp, tcfg, prompts, gen, 96,
+    ref = np.asarray(greedy_reference(se.engine.tp, tcfg, prompts, gen, 96,
                                       jitted))
-    states = [eng.prefill_batch(pt, 96) for pt in (prompts[:2], prompts[2:])]
-    pipe = eng.pipeline(0, tree=(3, 2))
-    s0, s1, _ = pipe.run(states, gen)
-    out, _ = eng.finalize([s0, s1], gen)
-    assert (out == ref).all()
-    assert pipe.trace_counts["fused"] == 1
-    assert pipe.trace_counts["rollback"] == 0      # commit is in-fused
+    reqs = [ServeRequest(i, np.asarray(prompts[i]), max_new_tokens=gen)
+            for i in range(b)]
+    for r in reqs:
+        se.submit(r)
+    assert len(se.run()) == b
+    for i, r in enumerate(reqs):
+        assert (r.result == ref[i]).all(), f"rid {r.rid}"
+    assert se.engine.trace_counts["fused"] == 1
+    assert se.engine.trace_counts["rollback"] == 0   # commit is in-fused
 
 
 def test_tree_pipeline_rejects_swa():
     tcfg = tiny_config(("attn",))
     bad = tiny_config(("swa",))
     with pytest.raises(ValueError):
-        InterleavedPipeline(None, tcfg, None, bad, 0, tree=(2,))
+        ServingEngine(bad, tcfg, config=SchedulerConfig(spec_tree=(2,)))
     with pytest.raises(ValueError):
         ServingEngine(tcfg, bad, config=SchedulerConfig(spec_tree=(2,)))
 
